@@ -17,7 +17,7 @@ import os
 from typing import Dict, List
 
 from repro.configs.base import SHAPES
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_KIND, chip_peaks
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
@@ -48,9 +48,10 @@ def model_flops(rec: Dict) -> float:
 def roofline_row(rec: Dict) -> Dict:
     coll_bytes = sum(v for k, v in rec["collectives"].items()
                      if not k.endswith("_count"))
-    compute_s = rec["flops"] / PEAK_FLOPS_BF16
-    memory_s = rec["bytes_accessed"] / HBM_BW
-    collective_s = coll_bytes / ICI_BW
+    peaks = chip_peaks(TARGET_KIND)      # the chip the dry-run targets
+    compute_s = rec["flops"] / peaks["flops_bf16"]
+    memory_s = rec["bytes_accessed"] / peaks["hbm_bw"]
+    collective_s = coll_bytes / peaks["ici_bw"]
     dom = max((("compute", compute_s), ("memory", memory_s),
                ("collective", collective_s)), key=lambda t: t[1])[0]
     mf = model_flops(rec)

@@ -2,11 +2,14 @@
 KV + AOT step latency, single-device vs a 1x2 host mesh
 (docs/SERVING.md#sharded-serving).
 
-Multi-device CPU requires ``xla_force_host_platform_device_count`` in
-XLA_FLAGS BEFORE the first jax import, which the parent harness (and
-anything else that already imported jax) cannot retrofit — so the
-measurement runs in a CHILD process this module re-execs with the flag
-set, and the parent parses one JSON line from its stdout.
+On a TPU host both engines run in this process, on the real chips (a
+chip belongs to one process: a child could not reach it while this
+process holds it).  On the CPU the devices are virtual:
+``xla_force_host_platform_device_count`` must be in XLA_FLAGS BEFORE
+the first jax import, which the parent harness (and anything else that
+already imported jax) cannot retrofit — so there the measurement runs in
+a CHILD process this module re-execs with the flag set, and the parent
+parses one JSON line from its stdout.
 
 Per engine (paged KV + int8 KV + speculative decoding all ON, the
 acceptance-criteria configuration):
@@ -33,7 +36,7 @@ _MESH = "1x2"
 
 
 def _serve_one(mesh: str | None, smoke: bool):
-    """Runs inside the child: one engine, full workload, measurements."""
+    """One engine, full workload, measurements."""
     import jax
 
     from repro.configs.base import ServeConfig
@@ -89,10 +92,13 @@ def _serve_one(mesh: str | None, smoke: bool):
                        "resident_kv_bytes", "spec_accepted")}}
 
 
+def _measure(smoke: bool) -> dict:
+    return {"single": _serve_one(None, smoke),
+            "mesh": _serve_one(_MESH, smoke)}
+
+
 def _child(smoke: bool) -> None:
-    out = {"single": _serve_one(None, smoke),
-           "mesh": _serve_one(_MESH, smoke)}
-    print("RESULT " + json.dumps(out))
+    print("RESULT " + json.dumps(_measure(smoke)))
 
 
 def _spawn(smoke: bool) -> dict:
@@ -118,7 +124,8 @@ def _spawn(smoke: bool) -> dict:
 
 
 def run(verbose: bool = True, smoke: bool = False):
-    res = _spawn(smoke)
+    import jax
+    res = _spawn(smoke) if jax.default_backend() == "cpu" else _measure(smoke)
     single, mesh = res["single"], res["mesh"]
     match = single["outputs"] == mesh["outputs"]
     assert match, (

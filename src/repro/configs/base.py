@@ -190,8 +190,9 @@ class ServeConfig:
     # with the fused extend/verify + decode kernels (page-read-once, no
     # dense pool copy); "xla" densifies via the gather path (the parity
     # reference, and the only fast option off-TPU — Pallas interpret
-    # mode is orders of magnitude slower).  None = auto: pallas on TPU,
-    # xla elsewhere.  Greedy outputs are token-identical either way
+    # mode is orders of magnitude slower).  None = auto: pallas on one
+    # TPU device, xla elsewhere and on a >1-device mesh, where an explicit
+    # "pallas" raises.  Greedy outputs are token-identical either way
     # (tests/test_paged_extend.py pins this).  docs/SERVING.md.
     attn_impl: Optional[str] = None
     max_think_tokens_low: int = 1024       # paper's "low" thinking budget

@@ -1,4 +1,4 @@
-"""qwen3-0.6b [dense] — qk_norm, GQA.  [hf:Qwen/Qwen3-8B]"""
+"""qwen3-0.6b [dense] — qk_norm, GQA.  [hf:Qwen/Qwen3-0.6B]"""
 from repro.configs.base import ModelConfig, dense_pattern
 
 CONFIG = ModelConfig(
@@ -16,7 +16,7 @@ CONFIG = ModelConfig(
     rope_theta=1_000_000.0,
     mlp_act="swiglu",
     tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-0.6B",
 )
 
 

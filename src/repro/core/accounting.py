@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Optional
 
+from repro.launch.mesh import TARGET_KIND, chip_peaks
 from repro.serving.request import TokenUsage
 
 CACHE_READ_DISCOUNT = 0.1
@@ -102,14 +103,14 @@ class LatencyModel:
 
 def roofline_step_seconds(flops_per_dev: float, bytes_per_dev: float,
                           collective_bytes: float,
-                          peak_flops: float = 197e12,
-                          hbm_bw: float = 819e9,
-                          ici_bw: float = 50e9) -> Dict[str, float]:
-    """The three §Roofline terms (seconds) + dominant bottleneck."""
+                          kind: str = TARGET_KIND) -> Dict[str, float]:
+    """The three §Roofline terms (seconds) + dominant bottleneck on a
+    chip of device kind ``kind`` (peaks from launch/mesh.CHIP_PEAKS)."""
+    peaks = chip_peaks(kind)
     terms = {
-        "compute_s": flops_per_dev / peak_flops,
-        "memory_s": bytes_per_dev / hbm_bw,
-        "collective_s": collective_bytes / ici_bw,
+        "compute_s": flops_per_dev / peaks["flops_bf16"],
+        "memory_s": bytes_per_dev / peaks["hbm_bw"],
+        "collective_s": collective_bytes / peaks["ici_bw"],
     }
     terms["bottleneck"] = max(("compute_s", "memory_s", "collective_s"),
                               key=lambda k: terms[k])

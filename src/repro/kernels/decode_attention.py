@@ -90,7 +90,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      k_zero: Optional[jax.Array] = None,
                      v_scale: Optional[jax.Array] = None,
                      window: Optional[int] = None, bk: int = 128,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """q: [B,K,G,hd]; k/v: [B,C,K,hd]; tok: [B,C]; pos: [B].
     With k_scale/k_zero/v_scale ([B,C,K] f32), k/v are int8 and
     dequantized inside the kernel."""

@@ -66,7 +66,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *, window: Optional[int] = None, bq: int = 128,
-                    bk: int = 128, interpret: bool = True) -> jax.Array:
+                    bk: int = 128, interpret: bool) -> jax.Array:
     """q: [B,H,S,hd]; k,v: [B,K,S,hd] -> [B,H,S,hd]."""
     B, H, S, hd = q.shape
     K = k.shape[1]

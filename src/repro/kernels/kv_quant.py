@@ -20,7 +20,7 @@ dim only):
 
 Scales/zeros are float32 sidecars shaped like the cache minus the head
 dim ([..., K] for a [..., K, hd] cache) — in paged mode they live in
-``[num_pages, page_size, K]`` pools that carry the same ``pages``
+``[num_pages, K, page_size]`` pools that carry the same ``pages``
 logical axis as the int8 payload, so copy-on-write, snapshot pinning
 and nbytes accounting move them with their pages for free.
 

@@ -45,7 +45,7 @@ def _mamba_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, dsk_ref, h0_ref,
 
 def mamba_scan(dt: jax.Array, Bm: jax.Array, Cm: jax.Array, x: jax.Array,
                A: jax.Array, Dsk: jax.Array, h0: jax.Array,
-               *, bd: int = 256, interpret: bool = True):
+               *, bd: int = 256, interpret: bool):
     """Returns (y [B,S,D] f32, h_last [B,D,N] f32)."""
     B, S, D = dt.shape
     N = A.shape[1]

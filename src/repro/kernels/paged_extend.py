@@ -1,48 +1,49 @@
-"""Pallas TPU paged extend/verify attention: Sx query lanes over a paged
-KV pool.
+"""Pallas TPU paged attention: Sx query lanes per request over a paged
+KV pool.  The one read kernel behind every paged engine step — decode
+(Sx=1), chunked-prefill / mixed steps, and the speculative VERIFY step
+([max_batch, 1 + spec_tokens]).
 
   q:          [B, Sx, K, G, hd]    (lane l sits at absolute pos0[b] + l)
-  k_pool:     [P, ps, K, hd]       (shared page pool, P physical pages)
-  v_pool:     [P, ps, K, hd]
+  k_pool:     [P, K, ps, hd]       (head-major page pool, P physical pages)
+  v_pool:     [P, K, ps, hd]
   page_table: [B, NP] int32        (logical page -> physical page, -1 = unmapped)
   pos0:       [B] int32            (absolute position of lane 0)
   out:        [B, Sx, K, G, hd]
 
-This is the kernel behind ``attention_extend_paged`` — the engine's
-HOTTEST wide step: every chunked-prefill chunk, every mixed step, and
-the speculative VERIFY step ([max_batch, 1 + spec_tokens]) go through
-it.  The XLA reference path densifies the ENTIRE pool into
-[B, NP*ps, K, hd] via ``_gather_pages`` on every call, so its byte
-traffic is O(pool) regardless of context.  Here the page table is a
-SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``) and the k/v
-BlockSpec index maps resolve ``page_table[b, j]`` into the page to DMA
-next, exactly like kernels/paged_attention.py — but with Sx*G query
-rows resident in VMEM at once, so EACH PAGE IS READ ONCE ACROSS ALL
-DRAFT/VERIFY/PREFILL LANES (the page-read-once contract) instead of
-once per dense copy.
+TPU tiling: a block's last two dims must be multiples of the (8, 128)
+tile or span the whole array dim.  The pool is HEAD-MAJOR so that one
+page block ``(1, K, ps, hd)`` spans its last three dims whole — legal
+for any page size and head count — and the int8 scale sidecars
+``[P, K, ps]`` likewise ride as ``(1, K, ps)`` blocks.  The page table
+and ``pos0`` are SCALAR-PREFETCH operands (``pltpu.PrefetchScalarGridSpec``,
+in SMEM): the k/v index maps resolve ``page_table[b, j]`` into the HBM
+page to DMA next, so the gather never materializes a dense
+[B, NP*ps, K, hd] copy the way the XLA path (``_gather_pages``) does,
+and each page is read ONCE for all Sx lanes and all K heads.
 
-Grid (B, K, NP/ppb) with the LAST axis sequential (TPU semantics):
-page blocks stream through VMEM while fp32 m/l/acc accumulators persist
-in scratch across iterations; the final iteration writes out.
-``pages_per_block`` (ppb) widens one sequential step to ppb page DMAs —
-physically scattered pages cannot form one block, so the pool rides in
-ppb times as separate BlockSpec'd inputs whose index maps walk
+Grid (B, NP/ppb) with the last axis sequential: page blocks stream
+through VMEM while fp32 m/l/acc accumulators persist in scratch; the
+final block writes out.  ``pages_per_block`` (ppb) widens one step to
+ppb page DMAs — physically scattered pages cannot form one block, so
+the pool rides in as ppb separate inputs whose index maps walk
 ``page_table[b, jb*ppb + i]``.  ``bq`` tiles the Sx*G query rows per
-matmul (MXU-shaped score tiles for wide prefill chunks).  Both come
-from the autotuned table (kernels/tuning.py) when not forced.
+matmul.  Both come from the autotuned table (kernels/tuning.py) when
+not forced.
 
-Masking is pure position arithmetic: lane l attends token t iff its
-page is mapped and ``t <= pos0 + l`` (and ``t > pos0 + l - window``
-when sliding-window).  Unmapped pages clamp to page 0 for the DMA and
-mask out of the softmax.  Pad lanes (engine ``n_valid``) compute
-garbage rows that no caller consumes — identical semantics to the XLA
-path, which also computes them.
+Query rows are laid out ``r = lane * G + g`` per kv head, so lane
+``r // G`` attends token ``t`` iff ``G * (t - pos0) <= r`` (causal) and
+``r < G * (t - pos0 + window)`` (sliding window) — integer products
+only, no vector division.  Pages that are unmapped, wholly in the
+future of every lane, or wholly behind the window skip their compute,
+and their index map repeats the nearest needed page so the pipeline
+issues no DMA for them.  Pad lanes (engine ``n_valid``) compute rows no
+caller consumes, exactly as the XLA path does.
 
-QUANTIZED mode (``k_scale``/``k_zero``/``v_scale`` pools [P, ps, K]
-f32; payload pools int8): sidecar pages ride the same page-table walk
-and tiles are dequantized in-register right before the QK^T / PV
-matmuls (asymmetric K, symmetric V — kernels/kv_quant.py), with fp32
-accumulators unchanged.
+QUANTIZED mode (``k_scale``/``k_zero``/``v_scale`` sidecar pools
+[P, K, ps] f32; payload pools int8): the scales fold into the score and
+probability rows (``s = ks * (q . kq) + sum(q) * (128 ks + kz)``;
+``p * vs`` before the PV matmul — the same factored dequant as the XLA
+path, kernels/kv_quant.py), with fp32 accumulators unchanged.
 """
 from __future__ import annotations
 
@@ -57,17 +58,29 @@ import jax.experimental.pallas.tpu as pltpu
 NEG_INF = -1e30
 
 
-def _extend_kernel(pt_ref, q_ref, *rest, ps: int, npb: int, ppb: int,
-                   sx: int, g: int, bq: int, scale: float,
-                   window: Optional[int], quant: bool):
+def _needed_pages(pos0, *, sx: int, ps: int, np_: int,
+                  window: Optional[int]):
+    """First and last logical page any lane of a row can attend."""
+    last = jnp.minimum((pos0 + sx - 1) // ps, np_ - 1)
+    if window is None:
+        return 0, last
+    first = jnp.minimum(jnp.maximum(pos0 - window + 1, 0) // ps, last)
+    return first, last
+
+
+def _kernel(pt_ref, pos_ref, q_ref, *rest, ps: int, np_: int, ppb: int,
+            npb: int, sx: int, g: int, heads: int, bq: int, scale: float,
+            window: Optional[int], quant: bool):
     """One body for fp and int8.  ``rest`` carries ppb interleaved page
-    refs — (k, v) or (k, v, ks, kz, vs) per sub-page — then pos, out and
-    the three fp32 scratch accumulators."""
+    refs — (k, v) or (k, v, ks, kz, vs) per sub-page — then the output
+    and the three fp32 scratch accumulators."""
     per = 5 if quant else 2
-    pages, rest = rest[:ppb * per], rest[ppb * per:]
-    pos_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    pages, (o_ref, m_ref, l_ref, acc_ref) = rest[:ppb * per], rest[ppb * per:]
     b = pl.program_id(0)
-    jb = pl.program_id(2)                                 # page-block index
+    jb = pl.program_id(1)
+    pos0 = pos_ref[b]
+    rows = sx * g
+    first, last = _needed_pages(pos0, sx=sx, ps=ps, np_=np_, window=window)
 
     @pl.when(jb == 0)
     def _init():
@@ -75,47 +88,51 @@ def _extend_kernel(pt_ref, q_ref, *rest, ps: int, npb: int, ppb: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    R = sx * g
-    q = (q_ref[0, :, 0].astype(jnp.float32) * scale).reshape(R, hd_ := q_ref.shape[-1])
-    # row r belongs to query lane r // g at absolute position pos0 + lane
-    lane = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 0) // g
-    pos_row = pos_ref[0, 0] + lane                        # [R, ps]
-
     for i in range(ppb):
-        refs = pages[i * per:(i + 1) * per]
-        k = refs[0][0, :, 0].astype(jnp.float32)          # [ps, hd]
-        v = refs[1][0, :, 0].astype(jnp.float32)
-        if quant:
-            ks, kz, vs = (r[0, :, 0] for r in refs[2:5])
-            k = (k + 128.0) * ks[:, None] + kz[:, None]
-            v = v * vs[:, None]
         j = jb * ppb + i                                  # logical page
-        mapped = pt_ref[b, j] >= 0
-        # absolute token index held by each slot of this logical page
-        t = j * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
-        valid = mapped & (t <= pos_row)
-        if window is not None:
-            valid = valid & (t > pos_row - window)
+        live = (pt_ref[b * np_ + j] >= 0) & (j >= first) & (j <= last)
 
-        for r0 in range(0, R, bq):
-            rs = slice(r0, min(r0 + bq, R))
-            s = jax.lax.dot_general(q[rs], k,
-                                    (((1,), (1,)), ((), ())))  # [bq, ps]
-            s = jnp.where(valid[rs], s, NEG_INF)
-            m_prev, l_prev = m_ref[rs], l_ref[rs]
-            acc_prev = acc_ref[rs]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])
-            corr = jnp.exp(m_prev - m_new)
-            l_new = l_prev * corr + jnp.sum(p, axis=1)
-            acc_new = acc_prev * corr[:, None] + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())))
-            m_ref[rs], l_ref[rs], acc_ref[rs] = m_new, l_new, acc_new
+        @pl.when(live)
+        def _page(i=i, j=j):
+            refs = pages[i * per:(i + 1) * per]
+            r = jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0)
+            d = j * ps - pos0 + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, ps), 1)                 # t - pos0
+            valid = d * g <= r
+            if window is not None:
+                valid = valid & (r < (d + window) * g)
+            for h in range(heads):
+                k = refs[0][0, h].astype(jnp.float32)     # [ps, hd]
+                v = refs[1][0, h].astype(jnp.float32)
+                if quant:
+                    ks, kz, vs = (x[0, h:h + 1, :] for x in refs[2:5])
+                for r0 in range(0, rows, bq):
+                    rs = slice(r0, min(r0 + bq, rows))
+                    q = q_ref[0, h, rs].astype(jnp.float32) * scale
+                    s = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())))   # [bq, ps]
+                    if quant:
+                        s = s * ks + (jnp.sum(q, axis=1, keepdims=True)
+                                      * (128.0 * ks + kz))
+                    s = jnp.where(valid[rs], s, NEG_INF)
+                    m_prev, l_prev = m_ref[h, rs], l_ref[h, rs]
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(s, axis=1, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    corr = jnp.exp(m_prev - m_new)
+                    l_ref[h, rs] = l_prev * corr + jnp.sum(p, axis=1,
+                                                           keepdims=True)
+                    if quant:
+                        p = p * vs
+                    acc_ref[h, rs] = acc_ref[h, rs] * corr + \
+                        jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
+                    m_ref[h, rs] = m_new
 
     @pl.when(jb == npb - 1)
     def _fin():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0] = out.reshape(sx, g, hd_).astype(o_ref.dtype)
+        for h in range(heads):
+            o_ref[0, h] = (acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+                           ).astype(o_ref.dtype)
 
 
 def paged_extend_attention(q: jax.Array, k_pool: jax.Array,
@@ -127,65 +144,67 @@ def paged_extend_attention(q: jax.Array, k_pool: jax.Array,
                            window: Optional[int] = None,
                            bq: Optional[int] = None,
                            pages_per_block: int = 1,
-                           interpret: bool = True) -> jax.Array:
-    """q: [B,Sx,K,G,hd]; k/v_pool: [P,ps,K,hd]; page_table: [B,NP];
-    pos0: [B].  With k_scale/k_zero/v_scale ([P,ps,K] f32 sidecar
-    pools) the payload pools are int8 and dequantized in-register."""
+                           interpret: bool) -> jax.Array:
+    """q: [B,Sx,K,G,hd]; k/v_pool: [P,K,ps,hd]; page_table: [B,NP];
+    pos0: [B].  With k_scale/k_zero/v_scale ([P,K,ps] f32 sidecar
+    pools) the payload pools are int8 and dequantized in-kernel."""
     B, Sx, K, G, hd = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     NP = page_table.shape[1]
-    scale = hd ** -0.5
     quant = k_scale is not None
-    assert quant == (k_zero is not None) == (v_scale is not None)
-    R = Sx * G
-    bq = R if bq is None else max(1, min(bq, R))
+    if quant != (k_zero is not None) or quant != (v_scale is not None):
+        raise ValueError("k_scale, k_zero and v_scale come together")
+    rows = Sx * G
+    bq = rows if bq is None else max(1, min(bq, rows))
     # physically scattered pages cannot widen a DMA block, so ppb rides as
     # ppb separate page-walk inputs; it must tile the table exactly
     ppb = max(d for d in range(1, max(1, pages_per_block) + 1)
               if NP % d == 0)
     npb = NP // ppb
-    pos2 = pos0[:, None].astype(jnp.int32)                # [B,1]
 
-    def kv_map(i):
-        # unmapped logical pages DMA physical page 0; the body masks them
-        return lambda b, h, jb, pt: (
-            jnp.maximum(pt[b, jb * ppb + i], 0), 0, h, 0)
-
-    def sc_map(i):
-        return lambda b, h, jb, pt: (
-            jnp.maximum(pt[b, jb * ppb + i], 0), 0, h)
+    def page_map(i, trailing):
+        def index_map(b, jb, pt, pos):
+            first, last = _needed_pages(pos[b], sx=Sx, ps=ps, np_=NP,
+                                        window=window)
+            j = jnp.clip(jb * ppb + i, first, last)
+            # unmapped pages DMA physical page 0; the body skips them
+            return (jnp.maximum(pt[b * NP + j], 0),) + (0,) * trailing
+        return index_map
 
     page_in, page_specs = [], []
     for i in range(ppb):
         page_in += [k_pool, v_pool]
-        page_specs += [pl.BlockSpec((1, ps, 1, hd), kv_map(i))] * 2
+        page_specs += [pl.BlockSpec((1, K, ps, hd), page_map(i, 3))] * 2
         if quant:
             page_in += [k_scale, k_zero, v_scale]
-            page_specs += [pl.BlockSpec((1, ps, 1), sc_map(i))] * 3
+            page_specs += [pl.BlockSpec((1, K, ps), page_map(i, 2))] * 3
 
-    kernel = functools.partial(_extend_kernel, ps=ps, npb=npb, ppb=ppb,
-                               sx=Sx, g=G, bq=bq, scale=scale,
-                               window=window, quant=quant)
+    kernel = functools.partial(_kernel, ps=ps, np_=NP, ppb=ppb, npb=npb,
+                               sx=Sx, g=G, heads=K, bq=bq,
+                               scale=hd ** -0.5, window=window, quant=quant)
+    row_spec = pl.BlockSpec((1, K, rows, hd), lambda b, jb, pt, pos:
+                            (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K, npb),
-        in_specs=[
-            pl.BlockSpec((1, Sx, 1, G, hd),
-                         lambda b, h, jb, pt: (b, 0, h, 0, 0)),
-            *page_specs,
-            pl.BlockSpec((1, 1), lambda b, h, jb, pt: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Sx, 1, G, hd),
-                               lambda b, h, jb, pt: (b, 0, h, 0, 0)),
+        num_scalar_prefetch=2,
+        grid=(B, npb),
+        in_specs=[row_spec, *page_specs],
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R, hd), jnp.float32),
+            pltpu.VMEM((K, rows, 1), jnp.float32),
+            pltpu.VMEM((K, rows, 1), jnp.float32),
+            pltpu.VMEM((K, rows, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    # rows r = lane * G + g per kv head
+    qh = jnp.transpose(q, (0, 2, 1, 3, 4)).reshape(B, K, rows, hd)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sx, K, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, rows, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), q, *page_in, pos2)
+        name="paged_attention",
+    )(page_table.astype(jnp.int32).reshape(-1), pos0.astype(jnp.int32), qh,
+      *page_in)
+    return jnp.transpose(out.reshape(B, K, Sx, G, hd), (0, 2, 1, 3, 4))

@@ -58,67 +58,47 @@ def decode_attention_ref(q, k, v, tok, pos, *, k_scale=None, k_zero=None,
     return o.astype(q.dtype)
 
 
+def _gather_logical(pool, page_table):
+    """Head-major pool leaf [P, K, ps, ...] + [B, NP] table -> dense
+    logical [B, NP*ps, K, ...] (unmapped entries gather page 0)."""
+    B, NP = page_table.shape
+    g = pool[jnp.maximum(page_table, 0)]                      # [B,NP,K,ps,...]
+    g = jnp.moveaxis(g, 3, 2)                                 # [B,NP,ps,K,...]
+    return g.reshape(B, NP * g.shape[2], *g.shape[3:])
+
+
 def paged_decode_attention_ref(q, k_pool, v_pool, page_table, pos, *,
                                k_scale=None, k_zero=None, v_scale=None,
                                window: Optional[int] = None):
-    """q: [B,K,G,hd]; k/v_pool: [P,ps,K,hd]; page_table: [B,NP]; pos: [B].
+    """q: [B,K,G,hd]; k/v_pool: [P,K,ps,hd]; page_table: [B,NP]; pos: [B].
 
-    Gathers each request's pages into a dense logical [B, NP*ps, K, hd]
-    view and applies position masking — the allclose target for the
-    page-table-walking Pallas kernel.  Optional scale sidecar pools
-    ([P,ps,K]) mark an int8 pool; they are gathered by the same table
-    and dequantized here.
-    """
-    B = q.shape[0]
-    ps = k_pool.shape[1]
-    NP = page_table.shape[1]
-    hd = q.shape[-1]
-    idx = jnp.maximum(page_table, 0)                          # [B,NP]
-
-    def gather(pool):
-        return pool[idx].reshape(B, NP * ps, *pool.shape[2:])
-
-    kg, vg = gather(k_pool), gather(v_pool)
-    if k_scale is not None:
-        kg = kv_quant.dequantize_k(kg, gather(k_scale), gather(k_zero))
-        vg = kv_quant.dequantize_v(vg, gather(v_scale))
-    qf = q.astype(jnp.float32)
-    s = jnp.einsum("bkgd,btkd->bkgt", qf, kg.astype(jnp.float32)) * hd ** -0.5
-    t = jnp.arange(NP * ps)[None, :]
-    mapped = jnp.repeat(page_table >= 0, ps, axis=1)
-    valid = mapped & (t <= pos[:, None])
-    if window is not None:
-        valid = valid & (t > pos[:, None] - window)
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", p, vg.astype(jnp.float32))
-    return o.astype(q.dtype)
+    One-lane case of :func:`paged_extend_attention_ref` (decode step)."""
+    return paged_extend_attention_ref(
+        q[:, None], k_pool, v_pool, page_table, pos, k_scale=k_scale,
+        k_zero=k_zero, v_scale=v_scale, window=window)[:, 0]
 
 
 def paged_extend_attention_ref(q, k_pool, v_pool, page_table, pos0, *,
                                k_scale=None, k_zero=None, v_scale=None,
                                window: Optional[int] = None):
-    """q: [B,Sx,K,G,hd]; k/v_pool: [P,ps,K,hd]; page_table: [B,NP];
+    """q: [B,Sx,K,G,hd]; k/v_pool: [P,K,ps,hd]; page_table: [B,NP];
     pos0: [B] (absolute position of query lane 0).
 
-    Dense-gather oracle for the paged extend/verify kernel: lane l of
+    Dense-gather oracle for the paged attention kernel: lane l of
     request b sits at ``pos0[b] + l`` and attends every mapped slot
     ``t <= pos0[b] + l`` (minus the sliding window, when set) — the
     per-lane staircase mask of ``attention_extend_paged``.  Optional
-    scale sidecar pools ([P,ps,K]) mark an int8 pool.
+    scale sidecar pools ([P,K,ps]) mark an int8 pool.
     """
     B, Sx, K, G, hd = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     NP = page_table.shape[1]
-    idx = jnp.maximum(page_table, 0)                          # [B,NP]
-
-    def gather(pool):
-        return pool[idx].reshape(B, NP * ps, *pool.shape[2:])
-
-    kg, vg = gather(k_pool), gather(v_pool)
+    kg = _gather_logical(k_pool, page_table)                  # [B,T,K,hd]
+    vg = _gather_logical(v_pool, page_table)
     if k_scale is not None:
-        kg = kv_quant.dequantize_k(kg, gather(k_scale), gather(k_zero))
-        vg = kv_quant.dequantize_v(vg, gather(v_scale))
+        kg = kv_quant.dequantize_k(kg, _gather_logical(k_scale, page_table),
+                                   _gather_logical(k_zero, page_table))
+        vg = kv_quant.dequantize_v(vg, _gather_logical(v_scale, page_table))
     qf = q.astype(jnp.float32)
     s = jnp.einsum("bskgd,btkd->bskgt", qf, kg.astype(jnp.float32)) \
         * hd ** -0.5

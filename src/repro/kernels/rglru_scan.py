@@ -29,7 +29,7 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, hs_ref, h_ref, *, seq: int):
 
 
 def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array,
-               *, bw: int = 512, interpret: bool = True):
+               *, bw: int = 512, interpret: bool):
     B, S, W = a.shape
     bw = min(bw, W)
     assert W % bw == 0, (W, bw)

@@ -24,9 +24,9 @@ unswept shape) degrades to exactly the old hardcoded behaviour.
 Regenerate with ``python benchmarks/kernels_micro.py --tune`` (see
 docs/SERVING.md): the sweep times each candidate with the live backend
 and records the winner alongside a roofline estimate
-(:func:`extend_cost_model_us`) built from the same HBM_BW /
-PEAK_FLOPS_BF16 peaks as benchmarks/roofline.py — candidates whose
-measured time beats the model are real wins, not timer noise.
+(:func:`extend_cost_model_us`) built from the chip's published peaks
+(launch/mesh.CHIP_PEAKS, keyed by device kind — a device without peaks,
+such as the CPU, cannot be tuned).
 """
 from __future__ import annotations
 
@@ -42,7 +42,6 @@ TABLE_PATH = os.path.join(os.path.dirname(__file__), "tuning_table.json")
 DEFAULTS: Dict[str, Dict] = {
     "flash": {"bq": 128, "bk": 128},
     "decode": {"bk": 128},
-    "paged_decode": {},
     "paged_extend": {"bq": 128, "pages_per_block": 1},
 }
 
@@ -104,17 +103,18 @@ def record(kernel: str, key: str, params: Dict, *, us: float,
 
 
 def extend_cost_model_us(*, B: int, Sx: int, K: int, G: int, hd: int,
-                         ctx: int, quant: bool = False) -> float:
-    """Two-term roofline for one paged-extend call (page-read-once):
-    bytes = each mapped KV byte ONCE + q/out, flops = QK^T + PV over the
-    causal extent.  Uses the same per-chip peaks as benchmarks/roofline.py;
-    this is the floor the kernel chases and the sanity bound the sweep
-    records next to measured times."""
-    from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+                         ctx: int, kind: str, quant: bool = False) -> float:
+    """Two-term roofline for one paged-extend call (page-read-once) on a
+    chip of device kind ``kind``: bytes = each mapped KV byte ONCE +
+    q/out, flops = QK^T + PV over the causal extent.  This is the floor
+    the kernel chases and the sanity bound the sweep records next to
+    measured times."""
+    from repro.launch.mesh import chip_peaks
+    peaks = chip_peaks(kind)
     kv_bytes = 2 * B * ctx * K * hd * (1 if quant else 4)
     if quant:
         kv_bytes += 3 * B * ctx * K * 4                    # scale sidecars
     io_bytes = 2 * B * Sx * K * G * hd * 4                 # q + out
     flops = 2 * 2 * B * Sx * K * G * hd * ctx              # QK^T + PV
-    return max(flops / PEAK_FLOPS_BF16,
-               (kv_bytes + io_bytes) / HBM_BW) * 1e6
+    return max(flops / peaks["flops_bf16"],
+               (kv_bytes + io_bytes) / peaks["hbm_bw"]) * 1e6

@@ -25,7 +25,7 @@ DEFAULT_RULES = {
     "heads": ("model",),
     "kv_heads": ("model",),
     "experts": ("model",),
-    # Paged KV pool leaves ([pages, page_size, kv_heads, hd]): the pool is
+    # Paged KV pool leaves ([pages, kv_heads, page_size, hd]): the pool is
     # sharded by PHYSICAL PAGE along 'model' — every device owns
     # num_pages/M pages of every layer.  kv_heads on the same leaf then
     # falls back to replicated (spec_for's used-axis rule), which is the
@@ -104,14 +104,10 @@ def spec_for(shape: Tuple[int, ...], axes: Tuple[Optional[str], ...],
 
 
 def _active_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh is None or mesh.empty:
-            return None
-        return mesh
-    except Exception:  # noqa: BLE001
-        return None
+    """The mesh of the enclosing ``with mesh:`` block, or None."""
+    from jax._src.mesh import thread_resources
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 def shard_activation(x: jax.Array, axes: Tuple[Optional[str], ...],

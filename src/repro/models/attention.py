@@ -477,10 +477,13 @@ def attention_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
 # Paged KV cache (vLLM-style shared page pool; docs/SERVING.md)
 # ---------------------------------------------------------------------------
 #
-# Layout per attention layer: ``{"kp": [P, ps, K, hd], "vp": [P, ps, K, hd]}``
+# Layout per attention layer: ``{"kp": [P, K, ps, hd], "vp": [P, K, ps, hd]}``
 # — a POOL of P physical pages of ps tokens each, shared by every request.
+# Pages are HEAD-MAJOR so the Pallas kernel's page block ``(1, K, ps, hd)``
+# spans its last dims whole, which the TPU's (8, 128) tiling requires of
+# a block (kernels/paged_extend.py).
 # With ``kv_dtype="int8"`` the pools are int8 and carry float32 scale
-# sidecar pools ``ksp``/``kzp``/``vsp`` ([P, ps, K]; asymmetric K,
+# sidecar pools ``ksp``/``kzp``/``vsp`` ([P, K, ps]; asymmetric K,
 # symmetric V — kernels/kv_quant.py): quantized at write time in
 # ``_paged_write``, dequantized at read via the factored scale-fold
 # (``_quant_scores*`` / ``_quant_pv*``) or in-register in the Pallas
@@ -493,7 +496,7 @@ def attention_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
 # arithmetic.  Writes are scatters into uniquely-owned pages (the serving
 # engine's copy-on-write invariant); reads gather the request's pages into
 # a dense logical view (XLA path) or walk the table page-by-page
-# (kernels/paged_attention.py).
+# (kernels/paged_extend.py).
 
 
 def paged_kv_cache_def(cfg: ModelConfig, num_pages: int, page_size: int,
@@ -507,7 +510,7 @@ def paged_kv_cache_def(cfg: ModelConfig, num_pages: int, page_size: int,
     ``kv_dtype`` (None = ModelConfig.kv_dtype): "int8" stores the pools
     quantized, with float32 scale SIDECAR pools ``ksp``/``kzp``
     (asymmetric K) and ``vsp`` (symmetric V) of shape
-    ``[num_pages, page_size, K]``.  The sidecars carry the same ``pages``
+    ``[num_pages, K, page_size]``.  The sidecars carry the same ``pages``
     axis as the payload, so every pages-axis mechanism — COW page
     copies, snapshot pins, per-page nbytes accounting — moves scales
     with their pages without special cases.
@@ -516,30 +519,31 @@ def paged_kv_cache_def(cfg: ModelConfig, num_pages: int, page_size: int,
     pool_dtype = (jnp.int8 if resolve_kv_dtype(cfg, kv_dtype) == "int8"
                   else dtype)
     d = {
-        "kp": L.ParamDef((num_pages, page_size, K, hd),
-                         ("pages", None, "kv_heads", None), pool_dtype,
+        "kp": L.ParamDef((num_pages, K, page_size, hd),
+                         ("pages", "kv_heads", None, None), pool_dtype,
                          init="zeros"),
-        "vp": L.ParamDef((num_pages, page_size, K, hd),
-                         ("pages", None, "kv_heads", None), pool_dtype,
+        "vp": L.ParamDef((num_pages, K, page_size, hd),
+                         ("pages", "kv_heads", None, None), pool_dtype,
                          init="zeros"),
     }
     if pool_dtype == jnp.int8:
         for leaf in ("ksp", "kzp", "vsp"):
-            d[leaf] = L.ParamDef((num_pages, page_size, K),
-                                 ("pages", None, "kv_heads"), jnp.float32,
+            d[leaf] = L.ParamDef((num_pages, K, page_size),
+                                 ("pages", "kv_heads", None), jnp.float32,
                                  init="zeros")
     return d
 
 
 def _gather_pages(pool_leaf: jax.Array, page_table: jax.Array) -> jax.Array:
-    """[P, ps, ...] pool + [B, NP] table -> dense logical [B, NP*ps, ...].
+    """Head-major [P, K, ps, ...] pool + [B, NP] table -> dense logical
+    [B, NP*ps, K, ...].
 
     Unmapped entries (-1) gather page 0; callers mask them by position.
     """
     idx = jnp.maximum(page_table, 0)
-    g = pool_leaf[idx]                                  # [B, NP, ps, ...]
+    g = jnp.moveaxis(pool_leaf[idx], 3, 2)              # [B, NP, ps, K, ...]
     B, NP, ps = g.shape[0], g.shape[1], g.shape[2]
-    out = g.reshape(B, NP * ps, *pool_leaf.shape[2:])
+    out = g.reshape(B, NP * ps, *g.shape[3:])
     # Mesh serving: the pool is sharded by physical page along 'model',
     # so this gather is an all-to-all.  Pin the densified result to the
     # attention compute layout — batch over 'data', kv_heads over
@@ -553,8 +557,9 @@ def _gather_pages(pool_leaf: jax.Array, page_table: jax.Array) -> jax.Array:
 
 def _paged_write(pool: Dict, k: jax.Array, v: jax.Array, phys: jax.Array,
                  off: jax.Array) -> Dict:
-    """Scatter K/V into pool pages.  phys/off: [B] or [B,Sx] (phys >= P
-    drops the write — the route for pad lanes and unmapped positions).
+    """Scatter K/V ([..., K, hd]) into head-major pool pages.  phys/off:
+    [B] or [B,Sx] (phys >= P drops the write — the route for pad lanes
+    and unmapped positions).
     Quantized pools (``ksp`` present) quantize HERE, at write time: the
     scales land at the same (page, offset) as their int8 rows.
 
@@ -573,19 +578,14 @@ def _paged_write(pool: Dict, k: jax.Array, v: jax.Array, phys: jax.Array,
     if "ksp" in pool:
         kq, ks, kz = Q.quantize_k(k)
         vq, vs = Q.quantize_v(v)
-        return {
-            "kp": pool["kp"].at[phys, off].set(kq, mode="drop"),
-            "vp": pool["vp"].at[phys, off].set(vq, mode="drop"),
-            "ksp": pool["ksp"].at[phys, off].set(ks, mode="drop"),
-            "kzp": pool["kzp"].at[phys, off].set(kz, mode="drop"),
-            "vsp": pool["vsp"].at[phys, off].set(vs, mode="drop"),
-        }
-    return {
-        "kp": pool["kp"].at[phys, off].set(k.astype(pool["kp"].dtype),
-                                           mode="drop"),
-        "vp": pool["vp"].at[phys, off].set(v.astype(pool["vp"].dtype),
-                                           mode="drop"),
-    }
+        vals = {"kp": kq, "vp": vq, "ksp": ks, "kzp": kz, "vsp": vs}
+    else:
+        vals = {"kp": k, "vp": v}
+    # indices around the head slice broadcast to the leading dims of the
+    # [..., K(, hd)] values
+    return {name: pool[name].at[phys, :, off].set(
+                val.astype(pool[name].dtype), mode="drop")
+            for name, val in vals.items()}
 
 
 def attention_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
@@ -595,13 +595,13 @@ def attention_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
                            ) -> Tuple[jax.Array, Dict]:
     """One-token decode over the page pool.  x: [B,1,d]; pos: [B];
     page_table: [B, NP] int32.  ``impl="pallas"`` reads the pool with the
-    page-table-walking kernel (kernels/paged_attention.py) instead of the
+    page-table-walking kernel (kernels/paged_extend.py) instead of the
     XLA ``_gather_pages`` densify; the write scatter stays XLA either
     way, and the kernel reads the post-write pool."""
     B = x.shape[0]
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // K
-    P, ps = pool["kp"].shape[0], pool["kp"].shape[1]
+    P, ps = pool["kp"].shape[0], pool["kp"].shape[2]
     NP = page_table.shape[1]
     q, k, v = _qkv(cfg, p, x, pos[:, None])
     lpage = jnp.clip(pos // ps, 0, NP - 1)
@@ -661,14 +661,14 @@ def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
     lane-deduplication or capacity clamp is needed.
 
     ``impl="pallas"`` reads the post-write pool with the fused paged
-    extend/verify kernel (kernels/paged_extend.py): each mapped page is
+    attention kernel (kernels/paged_extend.py): each mapped page is
     DMA'd once for all Sx lanes instead of densifying the whole pool via
     ``_gather_pages``.  Invalid lanes compute unused rows on both paths;
     the write scatter stays XLA on both paths."""
     B, Sx, _ = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // K
-    P, ps = pool["kp"].shape[0], pool["kp"].shape[1]
+    P, ps = pool["kp"].shape[0], pool["kp"].shape[2]
     NP = page_table.shape[1]
     positions = pos0[:, None] + jnp.arange(Sx)[None, :]          # [B,Sx]
     q, k, v = _qkv(cfg, p, x, positions)

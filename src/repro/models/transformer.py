@@ -152,7 +152,7 @@ class TransformerLM:
     def cache_defs_paged(self, batch: int, num_pages: int, page_size: int,
                          kv_dtype=None) -> PyTree:
         """Decode-cache defs with attention KV in a shared page pool
-        (scan-stacked pools are [layers, P, ps, K, hd]); recurrent layers
+        (scan-stacked pools are [layers, P, K, ps, hd]); recurrent layers
         keep their dense [batch, ...] state.  ``kv_dtype`` (None =
         ModelConfig.kv_dtype): "int8" adds per-page scale sidecar pools."""
         cfg = self.cfg

@@ -19,8 +19,8 @@ work rides along with each decode step, which is what bounds tail
 decode-step latency.
 
 KV memory is a PAGED POOL by default (``ServeConfig.paged_kv``;
-docs/SERVING.md): attention layers share one ``[num_pages, page_size,
-kv_heads, head_dim]`` pool per layer and each request owns a page table
+docs/SERVING.md): attention layers share one head-major ``[num_pages,
+kv_heads, page_size, head_dim]`` pool per layer and each request owns a page table
 mapping logical pages (position // page_size) to physical pages.  The
 page-pool design changes what the scheduler admits against — free pages
 instead of fixed ring capacity:
@@ -209,13 +209,14 @@ class Engine:
         # quantizes K/V pages at write time (scale sidecars travel with
         # their pages — docs/SERVING.md#quantized-kv-cache-int8).
         self.kv_dtype = scfg.kv_dtype or self.cfg.kv_dtype
-        # Paged-attention read implementation: Pallas page-table-walking
-        # kernels on TPU, XLA gather densify elsewhere (interpret-mode
-        # Pallas is a correctness tool, not a serving path).  Static per
-        # engine — it is baked into the jitted step closures below.  Under
-        # a >1-device mesh the Pallas kernels (no shard_map wrappers yet)
-        # fall back to the XLA gather path, which GSPMD partitions along
-        # the pool's sharded 'pages' axis (kernels/ops.resolve_attn_impl).
+        # Paged-attention read implementation: the Pallas page-table-
+        # walking kernel on one TPU device, XLA gather densify elsewhere
+        # (interpret-mode Pallas is a correctness tool, not a serving
+        # path).  Static per engine — it is baked into the jitted step
+        # closures below.  Under a >1-device mesh auto picks the XLA
+        # gather path, which GSPMD partitions along the pool's sharded
+        # 'pages' axis; an explicit "pallas" there raises (the kernel has
+        # no shard_map wrapper yet — kernels/ops.resolve_attn_impl).
         self.attn_impl = ops.resolve_attn_impl(scfg.attn_impl,
                                                self.n_devices)
         if self.paged:

@@ -35,8 +35,7 @@ def test_flash_attention_sweep(B, H, K, S, hd, window, dtype):
     q = _mk(ks[0], (B, H, S, hd), dtype)
     k = _mk(ks[1], (B, K, S, hd), dtype)
     v = _mk(ks[2], (B, K, S, hd), dtype)
-    got = ops.flash_attention(q, k, v, window=window, bq=64, bk=64,
-                              interpret=True)
+    got = ops.flash_attention(q, k, v, window=window, bq=64, bk=64)
     want = ref.flash_attention_ref(q, k, v, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -52,7 +51,7 @@ def test_flash_attention_block_invariance(bq, bk, seed):
     q = _mk(ks[0], (1, 2, 128, 32), jnp.float32)
     k = _mk(ks[1], (1, 2, 128, 32), jnp.float32)
     v = _mk(ks[2], (1, 2, 128, 32), jnp.float32)
-    got = ops.flash_attention(q, k, v, bq=bq, bk=bk, interpret=True)
+    got = ops.flash_attention(q, k, v, bq=bq, bk=bk)
     want = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5,
                                rtol=3e-5)
@@ -77,8 +76,7 @@ def test_decode_attention_sweep(B, K, G, C, hd, window, dtype):
     # ring occupancy: tokens 0..pos written (slot = t % C), rest empty
     tok = jnp.where(jnp.arange(C)[None, :] <= pos[:, None],
                     jnp.arange(C)[None, :], -1).astype(jnp.int32)
-    got = ops.decode_attention(q, k, v, tok, pos, window=window, bk=64,
-                               interpret=True)
+    got = ops.decode_attention(q, k, v, tok, pos, window=window, bk=64)
     want = ref.decode_attention_ref(q, k, v, tok, pos, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -95,11 +93,11 @@ def test_decode_attention_empty_slots_ignored():
     pos = jnp.array([20], jnp.int32)
     tok = jnp.where(jnp.arange(C)[None, :] <= 20,
                     jnp.arange(C)[None, :], -1).astype(jnp.int32)
-    got = ops.decode_attention(q, k, v, tok, pos, bk=64, interpret=True)
+    got = ops.decode_attention(q, k, v, tok, pos, bk=64)
     # poisoning empty slots must not change the result
     k2 = k.at[:, 21:].set(1e4)
     v2 = v.at[:, 21:].set(-1e4)
-    got2 = ops.decode_attention(q, k2, v2, tok, pos, bk=64, interpret=True)
+    got2 = ops.decode_attention(q, k2, v2, tok, pos, bk=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(got2), atol=1e-6)
 
 
@@ -121,7 +119,7 @@ def test_mamba_scan_sweep(B, S, D, N, bd):
     A = -jnp.exp(jax.random.normal(ks[4], (D, N)) * 0.3)
     Dsk = jax.random.normal(ks[5], (D,))
     h0 = jnp.zeros((B, D, N))
-    y, h = ops.mamba_scan(dt, Bm, Cm, x, A, Dsk, h0, bd=bd, interpret=True)
+    y, h = ops.mamba_scan(dt, Bm, Cm, x, A, Dsk, h0, bd=bd)
     y_ref, h_ref = ref.mamba_scan_ref(dt, Bm, Cm, x, A, Dsk, h0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4,
                                rtol=1e-4)
@@ -140,12 +138,12 @@ def test_mamba_scan_initial_state():
     A = -jnp.exp(jax.random.normal(ks[4], (D, N)) * 0.3)
     Dsk = jax.random.normal(ks[5], (D,))
     h0 = jnp.zeros((B, D, N))
-    y_full, h_full = ops.mamba_scan(dt, Bm, Cm, x, A, Dsk, h0, interpret=True)
+    y_full, h_full = ops.mamba_scan(dt, Bm, Cm, x, A, Dsk, h0)
     half = S // 2
     _, h1 = ops.mamba_scan(dt[:, :half], Bm[:, :half], Cm[:, :half],
-                           x[:, :half], A, Dsk, h0, interpret=True)
+                           x[:, :half], A, Dsk, h0)
     y2, h2 = ops.mamba_scan(dt[:, half:], Bm[:, half:], Cm[:, half:],
-                            x[:, half:], A, Dsk, h1, interpret=True)
+                            x[:, half:], A, Dsk, h1)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y_full[:, half:]),
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full), atol=1e-4,
@@ -164,7 +162,7 @@ def test_rglru_scan_property(seed, bw):
     a = jax.nn.sigmoid(jax.random.normal(ks[0], (B, S, W)))  # decay in (0,1)
     b = jax.random.normal(ks[1], (B, S, W))
     h0 = jax.random.normal(ks[2], (B, W))
-    hs, h = ops.rglru_scan(a, b, h0, bw=bw, interpret=True)
+    hs, h = ops.rglru_scan(a, b, h0, bw=bw)
     hs_ref, h_ref = ref.rglru_scan_ref(a, b, h0)
     np.testing.assert_allclose(np.asarray(hs), np.asarray(hs_ref), atol=1e-5,
                                rtol=1e-5)
@@ -181,6 +179,6 @@ def test_rglru_matches_model_block():
     b = jax.random.normal(ks[1], (B, S, W))
     h0 = jnp.zeros((B, W))
     hs_model, h_model = _chunked_scan(a, b, h0)
-    hs_kern, h_kern = ops.rglru_scan(a, b, h0, interpret=True)
+    hs_kern, h_kern = ops.rglru_scan(a, b, h0)
     np.testing.assert_allclose(np.asarray(hs_kern), np.asarray(hs_model),
                                atol=1e-5, rtol=1e-5)

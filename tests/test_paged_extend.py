@@ -28,8 +28,8 @@ def _inputs(B=3, Sx=6, K=2, G=4, hd=64, ps=16, NP=8, P=40, seed=0):
     lane frontier — the pool state mid-serve."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, Sx, K, G, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, ps, K, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, ps, K, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, K, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, K, ps, hd)), jnp.float32)
     pt = rng.permutation(P)[: B * NP].reshape(B, NP).astype(np.int32)
     pos0 = jnp.asarray([37, 90, NP * ps - Sx], jnp.int32)[:B]
     for b in range(B):
@@ -43,8 +43,7 @@ def _inputs(B=3, Sx=6, K=2, G=4, hd=64, ps=16, NP=8, P=40, seed=0):
 def test_extend_kernel_matches_oracle_fp(window, bq, ppb):
     q, kp, vp, pt, pos0 = _inputs()
     got = ops.paged_extend_attention(q, kp, vp, pt, pos0, window=window,
-                                     bq=bq, pages_per_block=ppb,
-                                     interpret=True)
+                                     bq=bq, pages_per_block=ppb)
     want = ref.paged_extend_attention_ref(q, kp, vp, pt, pos0,
                                           window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -59,7 +58,7 @@ def test_extend_kernel_matches_oracle_int8(window, ppb):
     vq, vs = Q.quantize_v(vp)
     got = ops.paged_extend_attention(q, kq, vq, pt, pos0, k_scale=ks,
                                      k_zero=kz, v_scale=vs, window=window,
-                                     pages_per_block=ppb, interpret=True)
+                                     pages_per_block=ppb)
     want = ref.paged_extend_attention_ref(q, kq, vq, pt, pos0, k_scale=ks,
                                           k_zero=kz, v_scale=vs,
                                           window=window)
@@ -71,9 +70,8 @@ def test_extend_kernel_single_lane_equals_decode_kernel():
     """An Sx=1 extend is a decode step: both kernels must agree on the
     same pool state (shared page-read-once contract)."""
     q, kp, vp, pt, pos0 = _inputs(Sx=1)
-    got = ops.paged_extend_attention(q, kp, vp, pt, pos0, interpret=True)
-    dec = ops.paged_decode_attention(q[:, 0], kp, vp, pt, pos0,
-                                     interpret=True)
+    got = ops.paged_extend_attention(q, kp, vp, pt, pos0)
+    dec = ops.paged_decode_attention(q[:, 0], kp, vp, pt, pos0)
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(dec),
                                atol=1e-4, rtol=1e-4)
 

@@ -1,9 +1,9 @@
 """Paged KV-cache subsystem correctness (docs/SERVING.md).
 
 Covers the contracts of serving/page_pool.py + the paged engine mode:
-  * parity — paged decode reproduces ring-cache decode exactly across
-    attention, MoE and hybrid-recurrent architectures, at the model level
-    (logits) and the engine level (tokens);
+  * parity — paged decode reproduces ring-cache decode across attention,
+    MoE and hybrid-recurrent architectures, to fp32 rounding at the model
+    level (logits) and exactly at the engine level (greedy tokens);
   * allocator — refcount-correct eviction (a pinned page is never
     reallocated), pool invariants hold through a full serving run;
   * preemption — pool exhaustion requeues the youngest request (never
@@ -54,10 +54,12 @@ def make_engine(arch="qwen3_0_6b", **kw):
 @pytest.mark.parametrize("arch", PARITY_ARCHS)
 def test_paged_decode_matches_ring(arch):
     """fp32 logits parity: chunked paged prefill + paged decode must
-    reproduce monolithic ring prefill + ring decode.  Full-attention and
-    MoE layers are BIT-identical (same score layout and mask); windowed
-    hybrid layers differ only in softmax summation order (ring slot
-    rotation vs linear pages), i.e. by float ulps."""
+    reproduce monolithic ring prefill + ring decode to fp32 rounding.
+    Not bit-for-bit: XLA's CPU dot kernels sum in an order that depends
+    on the contraction shape ([B, 5]-lane chunks against a gathered page
+    view vs one [B, 13] prefill against the ring), so the two walks
+    differ in the last ulps (~1e-6 here); windowed hybrid layers add
+    ring-rotation vs linear-page summation order on top."""
     cfg = get_smoke_config(arch).replace(dtype="float32", capacity_factor=8.0)
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
@@ -87,21 +89,14 @@ def test_paged_decode_matches_ring(arch):
         for b in range(B):
             if prog[b] == S and nv[b] > 0:
                 lg[b] = _f32(lg_new)[b]
-    exact = set(cfg.block_pattern) <= {"attn", "moe"}
-    if exact:
-        np.testing.assert_array_equal(lg, _f32(lg_ring))
-    else:
-        np.testing.assert_allclose(lg, _f32(lg_ring), atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(lg, _f32(lg_ring), atol=1e-4, rtol=1e-4)
 
     nxt = jnp.argmax(lg_ring, -1)[:, None].astype(jnp.int32)
     pos = jnp.full((B,), S, jnp.int32)
     d_ring, _ = m.decode_step(params, cache_ring, nxt, pos)
     d_paged, _ = m.decode_step(params, cache, nxt, pos, page_table=pt)
-    if exact:
-        np.testing.assert_array_equal(_f32(d_paged), _f32(d_ring))
-    else:
-        np.testing.assert_allclose(_f32(d_paged), _f32(d_ring), atol=1e-4,
-                                   rtol=1e-4)
+    np.testing.assert_allclose(_f32(d_paged), _f32(d_ring), atol=1e-4,
+                               rtol=1e-4)
     assert (np.argmax(_f32(d_paged), -1) == np.argmax(_f32(d_ring), -1)).all()
 
 
@@ -137,8 +132,8 @@ def test_paged_attention_kernel_parity(window):
     rng = np.random.default_rng(0)
     B, K, G, hd, P, ps, NP = 3, 2, 2, 32, 16, 8, 5
     q = jnp.asarray(rng.standard_normal((B, K, G, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((P, ps, K, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, ps, K, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((P, K, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((P, K, ps, hd)), jnp.float32)
     pos = jnp.asarray([3, 17, 38], jnp.int32)
     pt = np.full((B, NP), -1, np.int32)
     perm, u = rng.permutation(P), 0
@@ -147,8 +142,7 @@ def test_paged_attention_kernel_parity(window):
         pt[b, :n] = perm[u:u + n]
         u += n
     pt = jnp.asarray(pt)
-    got = ops.paged_decode_attention(q, kp, vp, pt, pos, window=window,
-                                     interpret=True)
+    got = ops.paged_decode_attention(q, kp, vp, pt, pos, window=window)
     want = ref.paged_decode_attention_ref(q, kp, vp, pt, pos, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
@@ -167,8 +161,8 @@ def test_paged_attention_kernel_parity(window):
                 t = lp * ps + o
                 if t > int(pos[b]):
                     continue
-                kd[b, t] = np.asarray(kp)[int(pt[b, lp]), o]
-                vd[b, t] = np.asarray(vp)[int(pt[b, lp]), o]
+                kd[b, t] = np.asarray(kp)[int(pt[b, lp]), :, o]
+                vd[b, t] = np.asarray(vp)[int(pt[b, lp]), :, o]
                 tok[b, t] = t
     dense = ref.decode_attention_ref(q, jnp.asarray(kd), jnp.asarray(vd),
                                      jnp.asarray(tok), pos, window=window)

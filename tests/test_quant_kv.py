@@ -93,8 +93,8 @@ def test_quant_paged_kernel_parity(window):
     rng = np.random.default_rng(0)
     B, K, G, hd, P, ps, NP = 3, 2, 2, 32, 16, 8, 5
     q = jnp.asarray(rng.standard_normal((B, K, G, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((P, ps, K, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, ps, K, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((P, K, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((P, K, ps, hd)), jnp.float32)
     pos = jnp.asarray([3, 17, 38], jnp.int32)
     pt = np.full((B, NP), -1, np.int32)
     perm, u = rng.permutation(P), 0
@@ -106,8 +106,7 @@ def test_quant_paged_kernel_parity(window):
     kq, ks, kz = Q.quantize_k(kp)
     vq, vs = Q.quantize_v(vp)
     got = ops.paged_decode_attention(q, kq, vq, pt, pos, k_scale=ks,
-                                     k_zero=kz, v_scale=vs, window=window,
-                                     interpret=True)
+                                     k_zero=kz, v_scale=vs, window=window)
     want = ref.paged_decode_attention_ref(q, kq, vq, pt, pos, k_scale=ks,
                                           k_zero=kz, v_scale=vs,
                                           window=window)
@@ -128,7 +127,7 @@ def test_quant_decode_kernel_parity():
     kq, ks, kz = Q.quantize_k(k)
     vq, vs = Q.quantize_v(v)
     got = ops.decode_attention(q, kq, vq, tok, pos, k_scale=ks, k_zero=kz,
-                               v_scale=vs, bk=16, interpret=True)
+                               v_scale=vs, bk=16)
     want = ref.decode_attention_ref(q, kq, vq, tok, pos, k_scale=ks,
                                     k_zero=kz, v_scale=vs)
     np.testing.assert_allclose(_f32(got), _f32(want), atol=2e-5, rtol=2e-5)
@@ -138,19 +137,53 @@ def test_quant_decode_kernel_parity():
 # model-level parity: quantized paged vs fp ring, within quant tolerance
 # ---------------------------------------------------------------------------
 
-# int8 KV error on these random-init smoke models: ~0.02 on the pure
-# attention / MoE stacks; the hybrid compounds it through rg_attn layers
-# feeding fp recurrences, so its bound is looser (still ~40x tighter than
-# the ~10.0 logit range).
-QUANT_ATOL = {"qwen3_0_6b": 0.08, "granite_moe_1b_a400m": 0.08,
+# int8 KV error on these random-init smoke models, in logits (range
+# ~5-7).  It grows with the size of the attention scores: qwen3 (qk-norm)
+# measures ~0.02.  granite has no qk-norm, so its random-init q.k scores
+# are several times larger and the same K quantization step moves them
+# further: ~0.21 at the last prefill position (a granite with plain
+# attention blocks in place of MoE measures ~0.98, and qwen3 with qk-norm
+# off ~0.58 — the MoE routing is not the cause).  The hybrid compounds
+# the error through rg_attn layers feeding fp recurrences.  That the
+# paged walk itself adds nothing is pinned exactly against the int8 ring
+# cache below.
+QUANT_ATOL = {"qwen3_0_6b": 0.08, "granite_moe_1b_a400m": 0.3,
               "recurrentgemma_9b": 0.4}
+
+
+def _chunked_walk(m, params, cache, tokens, page_table=None):
+    """Chunked prefill of ``tokens`` (rows advance 5 and 3 lanes per
+    step); returns (last-position logits, cache)."""
+    B, S = tokens.shape
+    kw = {} if page_table is None else {"page_table": page_table}
+    sizes, prog = [5, 3], [0, 0]
+    lg = np.zeros((B, m.cfg.vocab_size), np.float32)
+    while min(prog) < S:
+        blk = np.zeros((B, 5), np.int32)
+        nv = np.zeros(B, np.int32)
+        p0 = np.zeros(B, np.int32)
+        for b in range(B):
+            n = min(sizes[b], S - prog[b])
+            blk[b, :n] = np.asarray(tokens)[b, prog[b]:prog[b] + n]
+            nv[b], p0[b] = n, prog[b]
+            prog[b] += n
+        lg_new, cache = m.prefill_extend(params, cache, jnp.asarray(blk),
+                                         jnp.asarray(p0), jnp.asarray(nv),
+                                         **kw)
+        for b in range(B):
+            if prog[b] == S and nv[b] > 0:
+                lg[b] = _f32(lg_new)[b]
+    return lg, cache
 
 
 @pytest.mark.parametrize("arch", PARITY_ARCHS)
 def test_paged_quant_close_to_fp(arch):
     """Chunked int8 paged prefill + decode tracks the fp ring path within
     quantization tolerance across attn / MoE / hybrid models (the fp
-    counterpart of this walk is bit-identical — test_paged_kv.py)."""
+    counterpart of this walk agrees to fp32 rounding — test_paged_kv.py),
+    and reproduces the int8 RING cache under the same chunk walk: both
+    quantize the same rows the same way, so the head-major page pool,
+    its scale sidecars and the page-table walk add no error at all."""
     cfg = get_smoke_config(arch).replace(dtype="float32", capacity_factor=8.0)
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(0))
@@ -169,23 +202,12 @@ def test_paged_quant_close_to_fp(arch):
                                                       kv_dtype="int8"))):
         if d.axes and d.axes[0] == "pages" and leaf.ndim == 4:
             assert leaf.dtype == jnp.int8
-    sizes, prog = [5, 3], [0, 0]
-    lg = np.zeros((B, cfg.vocab_size), np.float32)
-    while min(prog) < S:
-        blk = np.zeros((B, 5), np.int32)
-        nv = np.zeros(B, np.int32)
-        p0 = np.zeros(B, np.int32)
-        for b in range(B):
-            n = min(sizes[b], S - prog[b])
-            blk[b, :n] = np.asarray(tokens)[b, prog[b]:prog[b] + n]
-            nv[b], p0[b] = n, prog[b]
-            prog[b] += n
-        lg_new, cache = m.prefill_extend(params, cache, jnp.asarray(blk),
-                                         jnp.asarray(p0), jnp.asarray(nv),
-                                         page_table=pt)
-        for b in range(B):
-            if prog[b] == S and nv[b] > 0:
-                lg[b] = _f32(lg_new)[b]
+    lg, cache = _chunked_walk(m, params, cache, tokens, pt)
+    lg_q, cache_q = _chunked_walk(
+        m, params, L.init_empty_cache(m.cache_defs(
+            B, max_seq, seq_shard=False, kv_dtype="int8")), tokens)
+    np.testing.assert_allclose(lg, lg_q, atol=1e-5, rtol=1e-5)
+
     atol = QUANT_ATOL[arch]
     np.testing.assert_allclose(lg, _f32(lg_ring), atol=atol, rtol=0.05)
     # the error must also be small relative to the logit spread
@@ -197,6 +219,9 @@ def test_paged_quant_close_to_fp(arch):
     pos = jnp.full((B,), S, jnp.int32)
     d_ring, _ = m.decode_step(params, cache_ring, nxt, pos)
     d_paged, _ = m.decode_step(params, cache, nxt, pos, page_table=pt)
+    d_q, _ = m.decode_step(params, cache_q, nxt, pos)
+    np.testing.assert_allclose(_f32(d_paged), _f32(d_q), atol=1e-5,
+                               rtol=1e-5)
     np.testing.assert_allclose(_f32(d_paged), _f32(d_ring), atol=atol,
                                rtol=0.05)
 
