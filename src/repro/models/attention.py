@@ -60,16 +60,17 @@ def attn_block_def(cfg: ModelConfig, dtype, window_attn: bool = False) -> Dict:
 # ---------------------------------------------------------------------------
 
 def _qkv(cfg: ModelConfig, p: Dict, x: jax.Array, positions: jax.Array):
-    dt = x.dtype
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
-    if cfg.qk_norm:
-        q = L.rmsnorm_heads(p["q_norm"], q, cfg.norm_eps)
-        k = L.rmsnorm_heads(p["k_norm"], k, cfg.norm_eps)
-    q = L.rope(q, positions, cfg.rope_theta)
-    k = L.rope(k, positions, cfg.rope_theta)
-    return q, k, v
+    with jax.named_scope("attn_in"):
+        dt = x.dtype
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(dt))
+        if cfg.qk_norm:
+            q = L.rmsnorm_heads(p["q_norm"], q, cfg.norm_eps)
+            k = L.rmsnorm_heads(p["k_norm"], k, cfg.norm_eps)
+        q = L.rope(q, positions, cfg.rope_theta)
+        k = L.rope(k, positions, cfg.rope_theta)
+        return q, k, v
 
 
 # ---------------------------------------------------------------------------
@@ -427,50 +428,60 @@ def attention_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
     # than a scatter: scatters onto the kv_seq-SHARDED capacity dim force
     # GSPMD to all-gather the cache per layer (§Perf hillclimb #3 — this
     # select is elementwise, so every shard updates locally).
-    slot = pos % C
-    hit = jnp.arange(C)[None, :] == slot[:, None]              # [B, C]
-    if "ks" in cache:                                   # quantized ring
-        kq, ks, kz = Q.quantize_k(k[:, 0:1])
-        vq, vs = Q.quantize_v(v[:, 0:1])
-        cache = {
-            "k": jnp.where(hit[:, :, None, None], kq, cache["k"]),
-            "v": jnp.where(hit[:, :, None, None], vq, cache["v"]),
-            "ks": jnp.where(hit[:, :, None], ks, cache["ks"]),
-            "kz": jnp.where(hit[:, :, None], kz, cache["kz"]),
-            "vs": jnp.where(hit[:, :, None], vs, cache["vs"]),
-            "tok": jnp.where(hit, pos[:, None], cache["tok"]),
-        }
-    else:
-        cache = {
-            "k": jnp.where(hit[:, :, None, None],
-                           k[:, 0:1].astype(cache["k"].dtype), cache["k"]),
-            "v": jnp.where(hit[:, :, None, None],
-                           v[:, 0:1].astype(cache["v"].dtype), cache["v"]),
-            "tok": jnp.where(hit, pos[:, None], cache["tok"]),
-        }
+    with jax.named_scope("kv_write"):
+        slot = pos % C
+        hit = jnp.arange(C)[None, :] == slot[:, None]          # [B, C]
+        if "ks" in cache:                               # quantized ring
+            kq, ks, kz = Q.quantize_k(k[:, 0:1])
+            vq, vs = Q.quantize_v(v[:, 0:1])
+            cache = {
+                "k": jnp.where(hit[:, :, None, None], kq, cache["k"]),
+                "v": jnp.where(hit[:, :, None, None], vq, cache["v"]),
+                "ks": jnp.where(hit[:, :, None], ks, cache["ks"]),
+                "kz": jnp.where(hit[:, :, None], kz, cache["kz"]),
+                "vs": jnp.where(hit[:, :, None], vs, cache["vs"]),
+                "tok": jnp.where(hit, pos[:, None], cache["tok"]),
+            }
+        else:
+            cache = {
+                "k": jnp.where(hit[:, :, None, None],
+                               k[:, 0:1].astype(cache["k"].dtype),
+                               cache["k"]),
+                "v": jnp.where(hit[:, :, None, None],
+                               v[:, 0:1].astype(cache["v"].dtype),
+                               cache["v"]),
+                "tok": jnp.where(hit, pos[:, None], cache["tok"]),
+            }
     q = q.reshape(B, K, G, hd)
-    scale = hd ** -0.5
-    quant = "ks" in cache
-    if quant:
-        scores = _quant_scores(q, cache["k"], cache["ks"],
-                               cache["kz"]) * scale
-    else:
-        scores = jnp.einsum("bkgd,btkd->bkgt", q,
-                            cache["k"].astype(x.dtype)) * scale
-        scores = scores.astype(jnp.float32)
-    tok = cache["tok"]
-    valid = (tok >= 0) & (tok <= pos[:, None])
-    if window is not None:
-        valid = valid & (tok > pos[:, None] - window)
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    prob = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    if quant:
-        out = _quant_pv(prob, cache["v"], cache["vs"])
-    else:
-        out = jnp.einsum("bkgt,btkd->bkgd", prob, cache["v"].astype(x.dtype))
-    out = out.reshape(B, 1, H, hd)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return y, cache
+    with jax.named_scope("attn_kernel"):
+        scale = hd ** -0.5
+        quant = "ks" in cache
+        if quant:
+            scores = _quant_scores(q, cache["k"], cache["ks"],
+                                   cache["kz"]) * scale
+        else:
+            scores = jnp.einsum("bkgd,btkd->bkgt", q,
+                                cache["k"].astype(x.dtype)) * scale
+            scores = scores.astype(jnp.float32)
+        tok = cache["tok"]
+        valid = (tok >= 0) & (tok <= pos[:, None])
+        if window is not None:
+            valid = valid & (tok > pos[:, None] - window)
+        scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+        prob = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        if quant:
+            out = _quant_pv(prob, cache["v"], cache["vs"])
+        else:
+            out = jnp.einsum("bkgt,btkd->bkgd", prob,
+                             cache["v"].astype(x.dtype))
+        out = out.reshape(B, 1, H, hd)
+    return _attn_out(p, out, x.dtype), cache
+
+
+def _attn_out(p: Dict, out: jax.Array, dt) -> jax.Array:
+    """The output projection of attention heads [B, S, H, hd] -> [B, S, d]."""
+    with jax.named_scope("attn_out"):
+        return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
 
 
 # ---------------------------------------------------------------------------
@@ -604,20 +615,32 @@ def attention_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
     P, ps = pool["kp"].shape[0], pool["kp"].shape[2]
     NP = page_table.shape[1]
     q, k, v = _qkv(cfg, p, x, pos[:, None])
-    lpage = jnp.clip(pos // ps, 0, NP - 1)
-    phys = jnp.take_along_axis(page_table, lpage[:, None], axis=1)[:, 0]
-    phys = jnp.where(phys >= 0, phys, P)                # unmapped -> dropped
-    pool = _paged_write(pool, k[:, 0], v[:, 0], phys, pos % ps)
+    with jax.named_scope("kv_write"):
+        lpage = jnp.clip(pos // ps, 0, NP - 1)
+        phys = jnp.take_along_axis(page_table, lpage[:, None], axis=1)[:, 0]
+        phys = jnp.where(phys >= 0, phys, P)            # unmapped -> dropped
+        pool = _paged_write(pool, k[:, 0], v[:, 0], phys, pos % ps)
 
     q = q.reshape(B, K, G, hd)
-    if impl == "pallas":
-        out = ops.paged_decode_attention(
-            q, pool["kp"], pool["vp"], page_table, pos,
-            k_scale=pool.get("ksp"), k_zero=pool.get("kzp"),
-            v_scale=pool.get("vsp"), window=window)
-        out = out.reshape(B, 1, H, hd).astype(x.dtype)
-        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-        return y, pool
+    with jax.named_scope("attn_kernel"):
+        if impl == "pallas":
+            out = ops.paged_decode_attention(
+                q, pool["kp"], pool["vp"], page_table, pos,
+                k_scale=pool.get("ksp"), k_zero=pool.get("kzp"),
+                v_scale=pool.get("vsp"), window=window)
+            out = out.reshape(B, 1, H, hd).astype(x.dtype)
+        else:
+            out = _decode_read_xla(q, pool, page_table, pos, window,
+                                   x.dtype).reshape(B, 1, H, hd)
+    return _attn_out(p, out, x.dtype), pool
+
+
+def _decode_read_xla(q, pool: Dict, page_table: jax.Array, pos: jax.Array,
+                     window: Optional[int], dt) -> jax.Array:
+    """The XLA read of one-token decode over the pool: ``_gather_pages``
+    densify, masked softmax, weighted V.  q: [B, K, G, hd]."""
+    hd, ps = q.shape[-1], pool["kp"].shape[2]
+    NP = page_table.shape[1]
     scale = hd ** -0.5
     quant = "ksp" in pool
     if quant:
@@ -626,7 +649,7 @@ def attention_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
                                _gather_pages(pool["ksp"], page_table),
                                _gather_pages(pool["kzp"], page_table)) * scale
     else:
-        kg = _gather_pages(pool["kp"], page_table).astype(x.dtype)
+        kg = _gather_pages(pool["kp"], page_table).astype(dt)
         scores = jnp.einsum("bkgd,btkd->bkgt", q, kg) * scale
         scores = scores.astype(jnp.float32)
     t = jnp.arange(NP * ps)[None, :]
@@ -634,16 +657,12 @@ def attention_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
     if window is not None:
         valid = valid & (t > pos[:, None] - window)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    prob = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+    prob = jax.nn.softmax(scores, axis=-1).astype(dt)
     if quant:
-        out = _quant_pv(prob, _gather_pages(pool["vp"], page_table),
-                        _gather_pages(pool["vsp"], page_table))
-    else:
-        vg = _gather_pages(pool["vp"], page_table).astype(x.dtype)
-        out = jnp.einsum("bkgt,btkd->bkgd", prob, vg)
-    out = out.reshape(B, 1, H, hd)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return y, pool
+        return _quant_pv(prob, _gather_pages(pool["vp"], page_table),
+                         _gather_pages(pool["vsp"], page_table))
+    vg = _gather_pages(pool["vp"], page_table).astype(dt)
+    return jnp.einsum("bkgt,btkd->bkgd", prob, vg)
 
 
 def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
@@ -672,23 +691,37 @@ def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
     NP = page_table.shape[1]
     positions = pos0[:, None] + jnp.arange(Sx)[None, :]          # [B,Sx]
     q, k, v = _qkv(cfg, p, x, positions)
-    lpage = jnp.clip(positions // ps, 0, NP - 1)
-    phys = jnp.take_along_axis(page_table, lpage, axis=1)        # [B,Sx]
-    keep = phys >= 0
-    if valid is not None:
-        keep = keep & valid
-    phys = jnp.where(keep, phys, P)                              # drop pads
-    pool = _paged_write(pool, k, v, phys, positions % ps)
+    with jax.named_scope("kv_write"):
+        lpage = jnp.clip(positions // ps, 0, NP - 1)
+        phys = jnp.take_along_axis(page_table, lpage, axis=1)    # [B,Sx]
+        keep = phys >= 0
+        if valid is not None:
+            keep = keep & valid
+        phys = jnp.where(keep, phys, P)                          # drop pads
+        pool = _paged_write(pool, k, v, phys, positions % ps)
 
     q = q.reshape(B, Sx, K, G, hd)
-    if impl == "pallas":
-        out = ops.paged_extend_attention(
-            q, pool["kp"], pool["vp"], page_table, pos0,
-            k_scale=pool.get("ksp"), k_zero=pool.get("kzp"),
-            v_scale=pool.get("vsp"), window=window)
-        out = out.reshape(B, Sx, H, hd).astype(x.dtype)
-        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-        return y, pool
+    with jax.named_scope("attn_kernel"):
+        if impl == "pallas":
+            out = ops.paged_extend_attention(
+                q, pool["kp"], pool["vp"], page_table, pos0,
+                k_scale=pool.get("ksp"), k_zero=pool.get("kzp"),
+                v_scale=pool.get("vsp"), window=window)
+            out = out.reshape(B, Sx, H, hd).astype(x.dtype)
+        else:
+            out = _extend_read_xla(q, pool, page_table, positions, window,
+                                   x.dtype).reshape(B, Sx, H, hd)
+    return _attn_out(p, out, x.dtype), pool
+
+
+def _extend_read_xla(q, pool: Dict, page_table: jax.Array,
+                     positions: jax.Array, window: Optional[int],
+                     dt) -> jax.Array:
+    """The XLA read of a multi-token extension over the pool:
+    ``_gather_pages`` densify, masked softmax, weighted V.
+    q: [B, Sx, K, G, hd]; positions: [B, Sx]."""
+    hd, ps = q.shape[-1], pool["kp"].shape[2]
+    NP = page_table.shape[1]
     scale = hd ** -0.5
     quant = "ksp" in pool
     if quant:
@@ -697,7 +730,7 @@ def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
             q, kg, _gather_pages(pool["ksp"], page_table),
             _gather_pages(pool["kzp"], page_table)) * scale
     else:
-        kg = _gather_pages(pool["kp"], page_table).astype(x.dtype)
+        kg = _gather_pages(pool["kp"], page_table).astype(dt)
         scores = jnp.einsum("bskgd,btkd->bkgst", q, kg) * scale
         scores = scores.astype(jnp.float32)
     t = jnp.arange(NP * ps)[None, None, :]
@@ -706,16 +739,12 @@ def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
     if window is not None:
         attendable = attendable & (t > positions[:, :, None] - window)
     scores = jnp.where(attendable[:, None, None, :, :], scores, NEG_INF)
-    prob = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+    prob = jax.nn.softmax(scores, axis=-1).astype(dt)
     if quant:
-        out = _quant_pv_ext(prob, _gather_pages(pool["vp"], page_table),
-                            _gather_pages(pool["vsp"], page_table))
-    else:
-        vg = _gather_pages(pool["vp"], page_table).astype(x.dtype)
-        out = jnp.einsum("bkgst,btkd->bskgd", prob, vg)
-    out = out.reshape(B, Sx, H, hd)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return y, pool
+        return _quant_pv_ext(prob, _gather_pages(pool["vp"], page_table),
+                             _gather_pages(pool["vsp"], page_table))
+    vg = _gather_pages(pool["vp"], page_table).astype(dt)
+    return jnp.einsum("bkgst,btkd->bskgd", prob, vg)
 
 
 # ---------------------------------------------------------------------------
@@ -861,56 +890,59 @@ def attention_extend(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
     C = cache["k"].shape[1]
     positions = pos0[:, None] + jnp.arange(Sx)[None, :]            # [B,Sx]
     q, k, v = _qkv(cfg, p, x, positions)
-    if valid is None:
-        slots = positions % C                                       # [B,Sx]
-        b = jnp.broadcast_to(jnp.arange(B)[:, None], (B, Sx))
-        if "ks" in cache:
-            kq, ks, kz = Q.quantize_k(k)
-            vq, vs = Q.quantize_v(v)
-            cache = {
-                "k": cache["k"].at[b, slots].set(kq),
-                "v": cache["v"].at[b, slots].set(vq),
-                "ks": cache["ks"].at[b, slots].set(ks),
-                "kz": cache["kz"].at[b, slots].set(kz),
-                "vs": cache["vs"].at[b, slots].set(vs),
-                "tok": cache["tok"].at[b, slots].set(positions),
-            }
+    with jax.named_scope("kv_write"):
+        if valid is None:
+            slots = positions % C                                   # [B,Sx]
+            b = jnp.broadcast_to(jnp.arange(B)[:, None], (B, Sx))
+            if "ks" in cache:
+                kq, ks, kz = Q.quantize_k(k)
+                vq, vs = Q.quantize_v(v)
+                cache = {
+                    "k": cache["k"].at[b, slots].set(kq),
+                    "v": cache["v"].at[b, slots].set(vq),
+                    "ks": cache["ks"].at[b, slots].set(ks),
+                    "kz": cache["kz"].at[b, slots].set(kz),
+                    "vs": cache["vs"].at[b, slots].set(vs),
+                    "tok": cache["tok"].at[b, slots].set(positions),
+                }
+            else:
+                cache = {
+                    "k": cache["k"].at[b, slots].set(
+                        k.astype(cache["k"].dtype)),
+                    "v": cache["v"].at[b, slots].set(
+                        v.astype(cache["v"].dtype)),
+                    "tok": cache["tok"].at[b, slots].set(positions),
+                }
         else:
-            cache = {
-                "k": cache["k"].at[b, slots].set(k.astype(cache["k"].dtype)),
-                "v": cache["v"].at[b, slots].set(v.astype(cache["v"].dtype)),
-                "tok": cache["tok"].at[b, slots].set(positions),
-            }
-    else:
-        cache = _masked_ring_write(cache, k, v, positions, valid)
+            cache = _masked_ring_write(cache, k, v, positions, valid)
     q = q.reshape(B, Sx, K, G, hd)
-    scale = hd ** -0.5
-    quant = "ks" in cache
-    if quant:
-        scores = _quant_scores_ext(q, cache["k"], cache["ks"],
-                                   cache["kz"]) * scale
-    else:
-        scores = jnp.einsum("bskgd,btkd->bkgst", q,
-                            cache["k"].astype(x.dtype)) * scale
-        scores = scores.astype(jnp.float32)
-    tok = cache["tok"]                                              # [B,C]
-    # distinct name from the `valid` lane mask: this is the [B,Sx,C]
-    # which-cache-slots-may-each-query-attend mask
-    attendable = ((tok[:, None, :] >= 0)
-                  & (tok[:, None, :] <= positions[:, :, None]))
-    if window is not None:
-        attendable = attendable & (tok[:, None, :]
-                                   > positions[:, :, None] - window)
-    scores = jnp.where(attendable[:, None, None, :, :], scores, NEG_INF)
-    prob = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    if quant:
-        out = _quant_pv_ext(prob, cache["v"], cache["vs"])
-    else:
-        out = jnp.einsum("bkgst,btkd->bskgd", prob,
-                         cache["v"].astype(x.dtype))
-    out = out.reshape(B, Sx, H, hd)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    return y, cache
+    with jax.named_scope("attn_kernel"):
+        scale = hd ** -0.5
+        quant = "ks" in cache
+        if quant:
+            scores = _quant_scores_ext(q, cache["k"], cache["ks"],
+                                       cache["kz"]) * scale
+        else:
+            scores = jnp.einsum("bskgd,btkd->bkgst", q,
+                                cache["k"].astype(x.dtype)) * scale
+            scores = scores.astype(jnp.float32)
+        tok = cache["tok"]                                          # [B,C]
+        # distinct name from the `valid` lane mask: this is the [B,Sx,C]
+        # which-cache-slots-may-each-query-attend mask
+        attendable = ((tok[:, None, :] >= 0)
+                      & (tok[:, None, :] <= positions[:, :, None]))
+        if window is not None:
+            attendable = attendable & (tok[:, None, :]
+                                       > positions[:, :, None] - window)
+        scores = jnp.where(attendable[:, None, None, :, :], scores, NEG_INF)
+        prob = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        if quant:
+            out = _quant_pv_ext(prob, cache["v"], cache["vs"])
+        else:
+            out = jnp.einsum("bkgst,btkd->bskgd", prob,
+                             cache["v"].astype(x.dtype))
+        out = out.reshape(B, Sx, H, hd)
+    return _attn_out(p, out, x.dtype), cache
 
 
 def attn_block_extend(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,

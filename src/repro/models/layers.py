@@ -104,11 +104,12 @@ def rmsnorm_def(dim: int, dtype=jnp.float32) -> PyTree:
 
 
 def rmsnorm(p, x, eps: float = 1e-6):
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    y = x * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"].astype(jnp.float32)).astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        x = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        y = x * jax.lax.rsqrt(var + eps)
+        return (y * p["scale"].astype(jnp.float32)).astype(dt)
 
 
 def rmsnorm_heads(scale, x, eps: float = 1e-6):
@@ -152,18 +153,19 @@ def mlp_def(d_model: int, d_ff: int, act: str, dtype=jnp.float32) -> PyTree:
 
 
 def mlp(p, x, act: str):
-    dt = x.dtype
-    h = jnp.einsum("...d,df->...f", x, p["wi"].astype(dt))
-    if act == "swiglu":
-        g = jnp.einsum("...d,df->...f", x, p["wg"].astype(dt))
-        h = jax.nn.silu(g) * h
-    elif act == "relu2":
-        h = jnp.square(jax.nn.relu(h))
-    elif act == "gelu":
-        h = jax.nn.gelu(h)
-    else:
-        raise ValueError(f"unknown act {act}")
-    return jnp.einsum("...f,fd->...d", h, p["wo"].astype(dt))
+    with jax.named_scope("mlp"):
+        dt = x.dtype
+        h = jnp.einsum("...d,df->...f", x, p["wi"].astype(dt))
+        if act == "swiglu":
+            g = jnp.einsum("...d,df->...f", x, p["wg"].astype(dt))
+            h = jax.nn.silu(g) * h
+        elif act == "relu2":
+            h = jnp.square(jax.nn.relu(h))
+        elif act == "gelu":
+            h = jax.nn.gelu(h)
+        else:
+            raise ValueError(f"unknown act {act}")
+        return jnp.einsum("...f,fd->...d", h, p["wo"].astype(dt))
 
 
 def embed_def(vocab: int, d_model: int, dtype=jnp.float32) -> ParamDef:

@@ -155,7 +155,8 @@ def moe_block_forward(cfg: ModelConfig, p: Dict, x: jax.Array,
     x = x + A.attention_full(cfg, p["attn"], h, positions,
                              cfg.sliding_window, lengths, prefix_len)
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    y, aux = moe_ffn(cfg, p["moe"], h)
+    with jax.named_scope("mlp"):
+        y, aux = moe_ffn(cfg, p["moe"], h)
     return x + y, aux
 
 
@@ -170,7 +171,8 @@ def moe_block_prefill(cfg: ModelConfig, p: Dict, x: jax.Array,
                                  cfg.sliding_window, lengths, prefix_len,
                                  out_dtype=x.dtype)
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    y, aux = moe_ffn(cfg, p["moe"], h)
+    with jax.named_scope("mlp"):
+        y, aux = moe_ffn(cfg, p["moe"], h)
     return x + y, cache, aux
 
 
@@ -187,7 +189,8 @@ def moe_block_extend(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                                       cfg.sliding_window, valid)
     x = x + y
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    y, _ = moe_ffn(cfg, p["moe"], h, valid=valid)
+    with jax.named_scope("mlp"):
+        y, _ = moe_ffn(cfg, p["moe"], h, valid=valid)
     return x + y, cache
 
 
@@ -204,5 +207,6 @@ def moe_block_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                                       cfg.sliding_window)
     x = x + y
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    y, _ = moe_ffn(cfg, p["moe"], h)
+    with jax.named_scope("mlp"):
+        y, _ = moe_ffn(cfg, p["moe"], h)
     return x + y, cache

@@ -192,15 +192,17 @@ class TransformerLM:
     # ---------------- embedding ------------------------------------------
 
     def embed(self, params: PyTree, tokens: jax.Array) -> jax.Array:
-        e = params["embed"].astype(self.dtype)
-        return e[tokens]
+        with jax.named_scope("embed"):
+            e = params["embed"].astype(self.dtype)
+            return e[tokens]
 
     def unembed(self, params: PyTree, x: jax.Array) -> jax.Array:
-        if self.cfg.tie_embeddings:
-            w = params["embed"].astype(self.dtype).T
-        else:
-            w = params["unembed"].astype(self.dtype)
-        return jnp.einsum("...d,dv->...v", x, w)
+        with jax.named_scope("head"):
+            if self.cfg.tie_embeddings:
+                w = params["embed"].astype(self.dtype).T
+            else:
+                w = params["unembed"].astype(self.dtype)
+            return jnp.einsum("...d,dv->...v", x, w)
 
     # ---------------- block application -----------------------------------
 

@@ -66,6 +66,7 @@ analysis reproduces.
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
@@ -73,6 +74,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, ServeConfig
 from repro.core.accounting import CostModel, LatencyModel
@@ -92,6 +94,17 @@ from repro.serving.speculator import (NGramSpeculator, draft_corpus,
 PyTree = Any
 
 COPY_BATCH = 8      # COW page copies applied per jitted scatter call
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the function name ``name``, which ``jax.jit`` gives
+    the program (HLO module ``jit_<name>``, and its ops' ``op_name``);
+    the argument names, and so the compiled program, stay ``fn``'s."""
+    @functools.wraps(fn)
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 class _StepFn:
@@ -114,12 +127,18 @@ class _StepFn:
     never feed an executable a mismatched layout — AOT executables,
     unlike plain jit, reject rather than reshard.  Compilation happens
     under ``with mesh`` so in-model shard_activation constraints bind.
+
+    Each call runs inside a ``serve.dispatch.<name>`` profiler span
+    (argument placement and the executable's launch), and the program
+    is jitted as ``serve_<name>`` (:func:`_named`), so a trace names its
+    HLO module ``jit_serve_<name>`` (docs/SERVING.md#tracing).
     """
 
     def __init__(self, fn, name: str, n_fixed: int, mesh=None,
                  in_shardings=None):
         self._fn = fn
         self.name = name
+        self._dispatch_span = f"serve.dispatch.{name}"
         self._n_fixed = n_fixed
         self._mesh = mesh
         self._in_sh = in_shardings
@@ -155,14 +174,15 @@ class _StepFn:
             self.warmed += 1
 
     def __call__(self, *args):
-        args = self._place(args)
-        key = self._key(args[self._n_fixed:])
-        exe = self._exe.get(key)
-        if exe is None:
-            exe = self._compile(args)
-            self._exe[key] = exe
-            self.compiles += 1
-        return exe(*args)
+        with TraceAnnotation(self._dispatch_span):
+            args = self._place(args)
+            key = self._key(args[self._n_fixed:])
+            exe = self._exe.get(key)
+            if exe is None:
+                exe = self._compile(args)
+                self._exe[key] = exe
+                self.compiles += 1
+            return exe(*args)
 
 
 class Engine:
@@ -395,6 +415,7 @@ class Engine:
         # donated cache output pinned to its input layout so residency
         # never drifts across steps.
         def _mk(fn, name, n_dyn, donate):
+            fn = _named(fn, f"serve_{name}")
             if self.mesh is None:
                 jit = jax.jit(fn, donate_argnums=(donate,))
                 return _StepFn(jit, name, n_fixed=donate + 1)
@@ -562,6 +583,13 @@ class Engine:
         }
         return self.compile_stats
 
+    def compiled_steps(self) -> List[Any]:
+        """Every compiled step executable (``jit_serve_*``); the
+        ``op_name`` metadata of their HLO names the model scope of each
+        device op in a profiler trace (docs/SERVING.md#tracing)."""
+        return [exe for f in self._step_fns().values()
+                for exe in f._exe.values()]
+
     def _kv_stats(self) -> Dict[str, Any]:
         """Resident-KV accounting, global and per device.  Pool leaves
         count only their USED pages (the pool is a capacity, not a
@@ -580,10 +608,7 @@ class Engine:
             total += int(nb * frac)
             per_dev += int(snb * frac)
         out = {"resident_kv_bytes": total,
-               "resident_kv_bytes_per_device": per_dev,
-               "allocated_kv_bytes": sum(
-                   x.size * x.dtype.itemsize
-                   for x in jax.tree_util.tree_leaves(self.cache))}
+               "resident_kv_bytes_per_device": per_dev}
         if self.paged:
             out["kv_pool_pages_used"] = self.pool.used_pages
             out["kv_pool_pages"] = self.pool.num_pages
@@ -1085,7 +1110,8 @@ class Engine:
         res = None
         cached_len = 0
         if self.prefix_cache is not None:
-            res = self.prefix_cache.lookup(target)
+            with TraceAnnotation("serve.prefix_lookup"):
+                res = self.prefix_cache.lookup(target)
             # a full-prompt hit still needs >=1 suffix token for logits
             cached_len = min(res.cached_len, len(target) - 1)
         if self.paged:
@@ -1403,76 +1429,88 @@ class Engine:
         spikes via the plan's virtual clock), expired deadlines finalize
         before any new work is planned, and the stall detector reaps
         in-flight rows after stall_limit consecutive no-progress steps —
-        all gated off by default (docs/SERVING.md#reliability)."""
-        if self.faults is not None:
-            self.faults.on_step()
-            if self.faults.fire("engine.crash") is not None:
-                self._crash_recover()
-                return (bool(self.queue)
-                        or any(r is not None for r in self.slots))
-            if self.faults.fire("engine.stuck") is not None:
-                self._mark_stuck()
-        if self.scfg.enforce_deadlines:
-            self._enforce_deadlines()
-        p0 = self._progress_seq
-        busy = self._step_inner()
-        if self.scfg.stall_limit > 0:
-            if (self._progress_seq == p0
-                    and any(r is not None for r in self.slots)):
-                self._no_progress += 1
-                if self._no_progress >= self.scfg.stall_limit:
-                    for slot, r in enumerate(self.slots):
-                        if r is not None:
-                            self._finalize_abnormal(r, slot, "stalled")
-                    self._no_progress = 0
-                    busy = (bool(self.queue)
+        all gated off by default (docs/SERVING.md#reliability).
+
+        The tick is the profiler span ``serve.step``; its phases are the
+        spans nested in it (docs/SERVING.md#tracing)."""
+        with TraceAnnotation("serve.step"):
+            if self.faults is not None:
+                self.faults.on_step()
+                if self.faults.fire("engine.crash") is not None:
+                    self._crash_recover()
+                    return (bool(self.queue)
                             or any(r is not None for r in self.slots))
-            else:
-                self._no_progress = 0
-        return busy
+                if self.faults.fire("engine.stuck") is not None:
+                    self._mark_stuck()
+            if self.scfg.enforce_deadlines:
+                self._enforce_deadlines()
+            p0 = self._progress_seq
+            busy = self._step_inner()
+            if self.scfg.stall_limit > 0:
+                if (self._progress_seq == p0
+                        and any(r is not None for r in self.slots)):
+                    self._no_progress += 1
+                    if self._no_progress >= self.scfg.stall_limit:
+                        for slot, r in enumerate(self.slots):
+                            if r is not None:
+                                self._finalize_abnormal(r, slot, "stalled")
+                        self._no_progress = 0
+                        busy = (bool(self.queue)
+                                or any(r is not None for r in self.slots))
+                else:
+                    self._no_progress = 0
+            return busy
+
+    def _page_table_arg(self) -> tuple:
+        """The page table as the paged step programs take it; nothing in
+        ring mode."""
+        return (self.page_tables.astype(np.int32),) if self.paged else ()
 
     def _step_inner(self) -> bool:
         """The scheduling core: admission, planning, one model call."""
         # admit queued requests into free slots (no model work yet);
         # SLO-unfundable requests finalize without consuming a slot
-        for slot in range(len(self.slots)):
-            while self.slots[slot] is None and self.queue:
-                req = self.queue.popleft()
-                if not self._slo_reject(req):
-                    self._admit(req, slot)
+        with TraceAnnotation("serve.admit"):
+            for slot in range(len(self.slots)):
+                while self.slots[slot] is None and self.queue:
+                    req = self.queue.popleft()
+                    if not self._slo_reject(req):
+                        self._admit(req, slot)
         if not any(r is not None for r in self.slots):
             return bool(self.queue)
 
-        self._fast_forward()
-        # speculative drafts first: decode rows outrank prefill for both
-        # pages and the step token budget (same decode-first policy as
-        # _ensure_decode_pages) — but drafts never eat the WHOLE budget
-        # while someone is prefilling (_clamp_drafts_to_budget), so a
-        # prefilling row keeps the non-spec guarantee of >=1 token of
-        # progress per step and can never be starved by speculation
-        drafts = self._make_drafts() if self.spec else {}
-        if drafts:
-            self._clamp_drafts_to_budget(drafts)
-        if self.paged:
-            # page admission control: decode rows first (they always get
-            # their committed-token page — drafts shrink before anyone is
-            # preempted), then prefill chunks sized to allocatable pages
-            self._ensure_decode_pages(drafts)
-        plan = self._plan_chunks(
-            width=min(self.chunk, 1 + self.spec_tokens) if drafts else None,
-            budget=(self.prefill_budget
-                    - sum(len(d) for d in drafts.values()))
-            if drafts else None)
-        if self.paged:
-            self._flush_copies()
-            pt = self.page_tables.astype(np.int32)
-        else:
-            pt = None
-        decode_rows = [i for i, r in enumerate(self.slots)
-                       if r is not None and r.status is Status.DECODING]
-        drafts = {s: d for s, d in drafts.items()
-                  if self.slots[s] is not None
-                  and self.slots[s].status is Status.DECODING}
+        with TraceAnnotation("serve.plan"):
+            self._fast_forward()
+            # speculative drafts first: decode rows outrank prefill for
+            # both pages and the step token budget (same decode-first
+            # policy as _ensure_decode_pages) — but drafts never eat the
+            # WHOLE budget while someone is prefilling
+            # (_clamp_drafts_to_budget), so a prefilling row keeps the
+            # non-spec guarantee of >=1 token of progress per step and
+            # can never be starved by speculation
+            drafts = self._make_drafts() if self.spec else {}
+            if drafts:
+                self._clamp_drafts_to_budget(drafts)
+            if self.paged:
+                # page admission control: decode rows first (they always
+                # get their committed-token page — drafts shrink before
+                # anyone is preempted), then prefill chunks sized to
+                # allocatable pages
+                self._ensure_decode_pages(drafts)
+            plan = self._plan_chunks(
+                width=min(self.chunk, 1 + self.spec_tokens) if drafts
+                else None,
+                budget=(self.prefill_budget
+                        - sum(len(d) for d in drafts.values()))
+                if drafts else None)
+            decode_rows = [i for i, r in enumerate(self.slots)
+                           if r is not None and r.status is Status.DECODING]
+            drafts = {s: d for s, d in drafts.items()
+                      if self.slots[s] is not None
+                      and self.slots[s].status is Status.DECODING}
+        if self._pending_copies:
+            with TraceAnnotation("serve.copy_pages"):
+                self._flush_copies()
         if not plan and not decode_rows:
             # pool pressure can leave a step with nothing runnable (all
             # rows preempted or waiting on pages freed next tick)
@@ -1489,7 +1527,7 @@ class Engine:
             # with chunks clamped to the verify width (planned above
             # under the shared token budget); starved prefill rows ride
             # as nv=0 no-op lanes exactly as in the mixed step.
-            return self._verify_step(plan, decode_rows, drafts, pt)
+            return self._verify_step(plan, decode_rows, drafts)
 
         if not plan and not starved:
             # decode fast path: dedicated [B, 1] step, no masked lanes.
@@ -1498,73 +1536,80 @@ class Engine:
             # nv=0 no-op — the decode step has no validity mask, so it
             # would scatter a stale (pos, next_token) into pages the row
             # already prefilled or shares copy-on-write.
-            tokens = self.next_token[:, None].astype(np.int32)
-            pos = self.pos.astype(np.int32)
-            args = (self.params, self.cache, tokens, pos)
-            logits, self.cache = (self._decode(*args, pt) if self.paged
-                                  else self._decode(*args))
-            logits = self._host_logits(logits)
-            self.model_steps["decode_batch_steps"] += 1
-            self.model_steps["decode_steps"] += len(decode_rows)
-            if self.faults is not None:
-                logits = self.faults.corrupt_logits("engine.logits", logits,
-                                                    decode_rows)
-            self._quarantine_rows(self._nonfinite_rows(logits, decode_rows))
-            sampled = self._sample_rows(logits)
-            for slot in decode_rows:
-                req = self.slots[slot]
-                if req is None or req.stuck:   # quarantined / fault-stuck
-                    continue
-                self._postprocess_decode(slot, sampled)
+            with TraceAnnotation("serve.inputs"):
+                tokens = self.next_token[:, None].astype(np.int32)
+                pos = self.pos.astype(np.int32)
+                args = (self.params, self.cache, tokens, pos,
+                        *self._page_table_arg())
+            logits, self.cache = self._decode(*args)
+            with TraceAnnotation("serve.sample"):
+                logits = self._host_logits(logits)
+                self.model_steps["decode_batch_steps"] += 1
+                self.model_steps["decode_steps"] += len(decode_rows)
+                if self.faults is not None:
+                    logits = self.faults.corrupt_logits("engine.logits",
+                                                        logits, decode_rows)
+                self._quarantine_rows(self._nonfinite_rows(logits,
+                                                           decode_rows))
+                sampled = self._sample_rows(logits)
+            with TraceAnnotation("serve.commit"):
+                for slot in decode_rows:
+                    req = self.slots[slot]
+                    if req is None or req.stuck:  # quarantined / fault-stuck
+                        continue
+                    self._postprocess_decode(slot, sampled)
             return True
 
         # mixed step: decode rows ride in lane 0; prefill rows get chunks.
         # Width = the smallest pre-compiled bucket that fits this step's
         # chunks (defaults to the single full-chunk bucket).
-        B = len(self.slots)
-        need = max(plan.values()) if plan else 1
-        W = next(w for w in self._mixed_buckets if w >= need)
-        toks = np.zeros((B, W), np.int32)
-        pos0 = np.zeros(B, np.int32)
-        nv = np.zeros(B, np.int32)
-        for slot in decode_rows:
-            toks[slot, 0] = self.next_token[slot]
-            pos0[slot] = self.pos[slot]
-            nv[slot] = 1
-        for slot, n in plan.items():
-            req = self.slots[slot]
-            target = req.prefill_target
-            toks[slot, :n] = target[req.prefill_pos:req.prefill_pos + n]
-            pos0[slot] = req.prefill_pos
-            nv[slot] = n
-        args = (self.params, self.cache, toks, pos0, nv)
-        logits, self.cache = (self._mixed(*args, pt) if self.paged
-                              else self._mixed(*args))
-        logits = self._host_logits(logits)
-        self.model_steps["mixed_steps"] += 1
-        self.model_steps["decode_steps"] += len(decode_rows)
-        self.model_steps["max_step_prefill_tokens"] = max(
-            self.model_steps["max_step_prefill_tokens"],
-            int(sum(plan.values())))
-        consumed = decode_rows + list(plan)
-        if self.faults is not None:
-            logits = self.faults.corrupt_logits("engine.logits", logits,
-                                                consumed)
-        self._quarantine_rows(self._nonfinite_rows(logits, consumed))
-        sampled = self._sample_rows(logits)
-        for slot, n in plan.items():
-            if self.slots[slot] is None:       # quarantined this step
-                continue
-            self._postprocess_prefill(slot, n, sampled)
-        for slot in decode_rows:
-            req = self.slots[slot]
-            if req is None or req.stuck:
-                continue
-            self._postprocess_decode(slot, sampled)
+        with TraceAnnotation("serve.inputs"):
+            B = len(self.slots)
+            need = max(plan.values()) if plan else 1
+            W = next(w for w in self._mixed_buckets if w >= need)
+            toks = np.zeros((B, W), np.int32)
+            pos0 = np.zeros(B, np.int32)
+            nv = np.zeros(B, np.int32)
+            for slot in decode_rows:
+                toks[slot, 0] = self.next_token[slot]
+                pos0[slot] = self.pos[slot]
+                nv[slot] = 1
+            for slot, n in plan.items():
+                req = self.slots[slot]
+                target = req.prefill_target
+                toks[slot, :n] = target[req.prefill_pos:req.prefill_pos + n]
+                pos0[slot] = req.prefill_pos
+                nv[slot] = n
+            args = (self.params, self.cache, toks, pos0, nv,
+                    *self._page_table_arg())
+        logits, self.cache = self._mixed(*args)
+        with TraceAnnotation("serve.sample"):
+            logits = self._host_logits(logits)
+            self.model_steps["mixed_steps"] += 1
+            self.model_steps["decode_steps"] += len(decode_rows)
+            self.model_steps["max_step_prefill_tokens"] = max(
+                self.model_steps["max_step_prefill_tokens"],
+                int(sum(plan.values())))
+            consumed = decode_rows + list(plan)
+            if self.faults is not None:
+                logits = self.faults.corrupt_logits("engine.logits", logits,
+                                                    consumed)
+            self._quarantine_rows(self._nonfinite_rows(logits, consumed))
+            sampled = self._sample_rows(logits)
+        with TraceAnnotation("serve.commit"):
+            for slot, n in plan.items():
+                if self.slots[slot] is None:       # quarantined this step
+                    continue
+                self._postprocess_prefill(slot, n, sampled)
+            for slot in decode_rows:
+                req = self.slots[slot]
+                if req is None or req.stuck:
+                    continue
+                self._postprocess_decode(slot, sampled)
         return True
 
     def _verify_step(self, plan: Dict[int, int], decode_rows: List[int],
-                     drafts: Dict[int, List[int]], pt) -> bool:
+                     drafts: Dict[int, List[int]]) -> bool:
         """One speculative verify step (docs/SERVING.md#speculative-decoding):
         score every row's committed token + drafted continuation in a
         single masked multi-token model call, then commit the longest
@@ -1573,60 +1618,64 @@ class Engine:
         prefill rows consume their planned chunks; the call returns
         logits for EVERY lane so acceptance is decided host-side from
         one device round-trip."""
-        B, W = len(self.slots), 1 + self.spec_tokens
-        toks = np.zeros((B, W), np.int32)
-        pos0 = np.zeros(B, np.int32)
-        nv = np.zeros(B, np.int32)
-        ndraft = np.zeros(B, np.int32)
-        for slot in decode_rows:
-            d = drafts.get(slot, [])
-            toks[slot, 0] = self.next_token[slot]
-            if d:
-                toks[slot, 1:1 + len(d)] = d
-            pos0[slot] = self.pos[slot]
-            nv[slot] = 1 + len(d)
-            ndraft[slot] = len(d)
-        for slot, n in plan.items():
-            req = self.slots[slot]
-            target = req.prefill_target
-            toks[slot, :n] = target[req.prefill_pos:req.prefill_pos + n]
-            pos0[slot] = req.prefill_pos
-            nv[slot] = n
-        args = (self.params, self.cache, toks, pos0, nv)
-        logits_all, self.cache = (self._verify(*args, pt) if self.paged
-                                  else self._verify(*args))
-        logits_all = self._host_logits(logits_all)
-        self.model_steps["verify_steps"] += 1
-        self.model_steps["decode_steps"] += len(decode_rows)
-        self.model_steps["max_step_prefill_tokens"] = max(
-            self.model_steps["max_step_prefill_tokens"],
-            int(sum(plan.values())))
-        consumed = decode_rows + list(plan)
-        if self.faults is not None:
-            logits_all = self.faults.corrupt_logits("engine.logits",
-                                                    logits_all, consumed)
-        self._quarantine_rows(
-            self._nonfinite_rows(logits_all, consumed, nv=nv, ndraft=ndraft))
-        temps = np.zeros(B, np.float32)
-        for i, r in enumerate(self.slots):
-            if r is not None:
-                temps[i] = r.temperature
-        self.rng, k = jax.random.split(self.rng)
-        n_emit, emit = sampler.verify_batch(
-            logits_all, jnp.asarray(toks), jnp.asarray(nv),
-            jnp.asarray(ndraft), k, jnp.asarray(temps))
-        n_emit = np.asarray(n_emit)
-        emit = np.asarray(emit)
-        # prefill rows: emit[:, 0] is the sample at their last valid lane
-        # (n_draft=0 rows verify nothing), exactly _sample_rows' output
-        for slot, n in plan.items():
-            if self.slots[slot] is None:       # quarantined this step
-                continue
-            self._postprocess_prefill(slot, n, emit[:, 0])
-        for slot in decode_rows:
-            req = self.slots[slot]
-            if req is None or req.stuck:
-                continue
-            self._postprocess_verify(slot, int(n_emit[slot]), emit[slot],
-                                     int(ndraft[slot]))
+        with TraceAnnotation("serve.inputs"):
+            B, W = len(self.slots), 1 + self.spec_tokens
+            toks = np.zeros((B, W), np.int32)
+            pos0 = np.zeros(B, np.int32)
+            nv = np.zeros(B, np.int32)
+            ndraft = np.zeros(B, np.int32)
+            for slot in decode_rows:
+                d = drafts.get(slot, [])
+                toks[slot, 0] = self.next_token[slot]
+                if d:
+                    toks[slot, 1:1 + len(d)] = d
+                pos0[slot] = self.pos[slot]
+                nv[slot] = 1 + len(d)
+                ndraft[slot] = len(d)
+            for slot, n in plan.items():
+                req = self.slots[slot]
+                target = req.prefill_target
+                toks[slot, :n] = target[req.prefill_pos:req.prefill_pos + n]
+                pos0[slot] = req.prefill_pos
+                nv[slot] = n
+            args = (self.params, self.cache, toks, pos0, nv,
+                    *self._page_table_arg())
+        logits_all, self.cache = self._verify(*args)
+        with TraceAnnotation("serve.sample"):
+            logits_all = self._host_logits(logits_all)
+            self.model_steps["verify_steps"] += 1
+            self.model_steps["decode_steps"] += len(decode_rows)
+            self.model_steps["max_step_prefill_tokens"] = max(
+                self.model_steps["max_step_prefill_tokens"],
+                int(sum(plan.values())))
+            consumed = decode_rows + list(plan)
+            if self.faults is not None:
+                logits_all = self.faults.corrupt_logits("engine.logits",
+                                                        logits_all, consumed)
+            self._quarantine_rows(self._nonfinite_rows(
+                logits_all, consumed, nv=nv, ndraft=ndraft))
+            temps = np.zeros(B, np.float32)
+            for i, r in enumerate(self.slots):
+                if r is not None:
+                    temps[i] = r.temperature
+            self.rng, k = jax.random.split(self.rng)
+            n_emit, emit = sampler.verify_batch(
+                logits_all, jnp.asarray(toks), jnp.asarray(nv),
+                jnp.asarray(ndraft), k, jnp.asarray(temps))
+            n_emit = np.asarray(n_emit)
+            emit = np.asarray(emit)
+        with TraceAnnotation("serve.commit"):
+            # prefill rows: emit[:, 0] is the sample at their last valid
+            # lane (n_draft=0 rows verify nothing), exactly _sample_rows'
+            # output
+            for slot, n in plan.items():
+                if self.slots[slot] is None:       # quarantined this step
+                    continue
+                self._postprocess_prefill(slot, n, emit[:, 0])
+            for slot in decode_rows:
+                req = self.slots[slot]
+                if req is None or req.stuck:
+                    continue
+                self._postprocess_verify(slot, int(n_emit[slot]), emit[slot],
+                                         int(ndraft[slot]))
         return True
