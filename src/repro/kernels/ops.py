@@ -79,38 +79,45 @@ def decode_attention(q, k, v, tok, pos, *, k_scale=None, k_zero=None,
                    interpret=interpret_mode())
 
 
-def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
-                           k_scale=None, k_zero=None, v_scale=None,
+def paged_decode_attention(q, k_pool, v_pool, page_table, pos, layer=None,
+                           *, k_scale=None, k_zero=None, v_scale=None,
                            window: Optional[int] = None):
     """One-token decode over the page pool (q: [B,K,G,hd]): the paged
-    kernel at Sx=1.  Scale sidecar pools ([P,K,ps] f32) select the
+    kernel at Sx=1.  Pools and ``layer`` as in
+    :func:`paged_extend_attention`; scale sidecar pools select the
     fused-dequant int8 variant (pools int8)."""
     return paged_extend_attention(
-        q[:, None], k_pool, v_pool, page_table, pos, k_scale=k_scale,
+        q[:, None], k_pool, v_pool, page_table, pos, layer, k_scale=k_scale,
         k_zero=k_zero, v_scale=v_scale, window=window)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bq",
                                              "pages_per_block"))
-def paged_extend_attention(q, k_pool, v_pool, page_table, pos0, *,
-                           k_scale=None, k_zero=None, v_scale=None,
+def paged_extend_attention(q, k_pool, v_pool, page_table, pos0, layer=None,
+                           *, k_scale=None, k_zero=None, v_scale=None,
                            window: Optional[int] = None,
                            bq: Optional[int] = None,
                            pages_per_block: Optional[int] = None):
-    """Paged multi-lane extend/verify attention (q: [B,Sx,K,G,hd];
-    pools [P,K,ps,hd]); the kernel behind decode, chunked prefill and
-    speculative verify.  Scale sidecar pools ([P,K,ps] f32) select the
-    fused-dequant int8 variant."""
+    """Paged multi-lane extend/verify attention (q: [B,Sx,K,G,hd]); the
+    kernel behind decode, chunked prefill and speculative verify.  Pools
+    are the layer scan's stack [L,P,K,ps,hd] read at ``layer``, or with
+    ``layer`` None one layer's [P,K,ps,hd].  Scale sidecar pools
+    ([(L,)P,K,ps] f32) select the fused-dequant int8 variant."""
+    if layer is None:
+        k_pool, v_pool, k_scale, k_zero, v_scale = (
+            None if a is None else a[None]
+            for a in (k_pool, v_pool, k_scale, k_zero, v_scale))
+        layer = 0
     if bq is None or pages_per_block is None:
         B, Sx, K, G, hd = q.shape
         tuned = tuning.lookup("paged_extend", r=Sx * G, hd=hd,
-                              ctx=page_table.shape[1] * k_pool.shape[2])
+                              ctx=page_table.shape[1] * k_pool.shape[3])
         bq = tuned["bq"] if bq is None else bq
         if pages_per_block is None:
             pages_per_block = tuned["pages_per_block"]
-    return _paged(q, k_pool, v_pool, page_table, pos0, k_scale=k_scale,
-                  k_zero=k_zero, v_scale=v_scale, window=window, bq=bq,
-                  pages_per_block=pages_per_block,
+    return _paged(q, k_pool, v_pool, page_table, pos0, layer,
+                  k_scale=k_scale, k_zero=k_zero, v_scale=v_scale,
+                  window=window, bq=bq, pages_per_block=pages_per_block,
                   interpret=interpret_mode())
 
 

@@ -4,20 +4,29 @@ KV pool.  The one read kernel behind every paged engine step — decode
 ([max_batch, 1 + spec_tokens]).
 
   q:          [B, Sx, K, G, hd]    (lane l sits at absolute pos0[b] + l)
-  k_pool:     [P, K, ps, hd]       (head-major page pool, P physical pages)
-  v_pool:     [P, K, ps, hd]
+  k_pool:     [L, P, K, ps, hd]    (stacked head-major page pools, one per
+                                    layer, P physical pages each)
+  v_pool:     [L, P, K, ps, hd]
   page_table: [B, NP] int32        (logical page -> physical page, -1 = unmapped)
   pos0:       [B] int32            (absolute position of lane 0)
+  layer:      int32 scalar         (which of the L pools this call reads)
   out:        [B, Sx, K, G, hd]
+
+The kernel reads the layer scan's whole stacked pool in place
+(docs/SERVING.md#the-pool-through-the-layer-scan): slicing one layer's
+pool out first would materialize a pool-sized copy per layer per step.
+A single layer's pool ``[P, K, ps, hd]`` is the stack ``pool[None]`` at
+layer 0.
 
 TPU tiling: a block's last two dims must be multiples of the (8, 128)
 tile or span the whole array dim.  The pool is HEAD-MAJOR so that one
-page block ``(1, K, ps, hd)`` spans its last three dims whole — legal
-for any page size and head count — and the int8 scale sidecars
-``[P, K, ps]`` likewise ride as ``(1, K, ps)`` blocks.  The page table
-and ``pos0`` are SCALAR-PREFETCH operands (``pltpu.PrefetchScalarGridSpec``,
-in SMEM): the k/v index maps resolve ``page_table[b, j]`` into the HBM
-page to DMA next, so the gather never materializes a dense
+page block ``(None, 1, K, ps, hd)`` — the layer dim squeezed — spans its
+last three dims whole, legal for any page size and head count, and the
+int8 scale sidecars ``[L, P, K, ps]`` likewise ride as
+``(None, 1, K, ps)`` blocks.  The page table, ``pos0`` and ``layer`` are
+SCALAR-PREFETCH operands (``pltpu.PrefetchScalarGridSpec``, in SMEM):
+the k/v index maps resolve ``(layer, page_table[b, j], 0, 0, 0)`` into
+the HBM page to DMA next, so the gather never materializes a dense
 [B, NP*ps, K, hd] copy the way the XLA path (``_gather_pages``) does,
 and each page is read ONCE for all Sx lanes and all K heads.
 
@@ -40,7 +49,7 @@ issues no DMA for them.  Pad lanes (engine ``n_valid``) compute rows no
 caller consumes, exactly as the XLA path does.
 
 QUANTIZED mode (``k_scale``/``k_zero``/``v_scale`` sidecar pools
-[P, K, ps] f32; payload pools int8): the scales fold into the score and
+[L, P, K, ps] f32; payload pools int8): the scales fold into the score and
 probability rows (``s = ks * (q . kq) + sum(q) * (128 ks + kz)``;
 ``p * vs`` before the PV matmul — the same factored dequant as the XLA
 path, kernels/kv_quant.py), with fp32 accumulators unchanged.
@@ -68,12 +77,13 @@ def _needed_pages(pos0, *, sx: int, ps: int, np_: int,
     return first, last
 
 
-def _kernel(pt_ref, pos_ref, q_ref, *rest, ps: int, np_: int, ppb: int,
-            npb: int, sx: int, g: int, heads: int, bq: int, scale: float,
-            window: Optional[int], quant: bool):
+def _kernel(pt_ref, pos_ref, layer_ref, q_ref, *rest, ps: int, np_: int,
+            ppb: int, npb: int, sx: int, g: int, heads: int, bq: int,
+            scale: float, window: Optional[int], quant: bool):
     """One body for fp and int8.  ``rest`` carries ppb interleaved page
     refs — (k, v) or (k, v, ks, kz, vs) per sub-page — then the output
-    and the three fp32 scratch accumulators."""
+    and the three fp32 scratch accumulators.  ``layer_ref`` is read by
+    the index maps alone."""
     per = 5 if quant else 2
     pages, (o_ref, m_ref, l_ref, acc_ref) = rest[:ppb * per], rest[ppb * per:]
     b = pl.program_id(0)
@@ -137,7 +147,7 @@ def _kernel(pt_ref, pos_ref, q_ref, *rest, ps: int, np_: int, ppb: int,
 
 def paged_extend_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, page_table: jax.Array,
-                           pos0: jax.Array,
+                           pos0: jax.Array, layer,
                            *, k_scale: Optional[jax.Array] = None,
                            k_zero: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
@@ -145,11 +155,12 @@ def paged_extend_attention(q: jax.Array, k_pool: jax.Array,
                            bq: Optional[int] = None,
                            pages_per_block: int = 1,
                            interpret: bool) -> jax.Array:
-    """q: [B,Sx,K,G,hd]; k/v_pool: [P,K,ps,hd]; page_table: [B,NP];
-    pos0: [B].  With k_scale/k_zero/v_scale ([P,K,ps] f32 sidecar
-    pools) the payload pools are int8 and dequantized in-kernel."""
+    """q: [B,Sx,K,G,hd]; k/v_pool: [L,P,K,ps,hd]; page_table: [B,NP];
+    pos0: [B]; layer: int32 scalar in [0, L).  With k_scale/k_zero/
+    v_scale ([L,P,K,ps] f32 sidecar pools) the payload pools are int8
+    and dequantized in-kernel."""
     B, Sx, K, G, hd = q.shape
-    ps = k_pool.shape[2]
+    ps = k_pool.shape[3]
     NP = page_table.shape[1]
     quant = k_scale is not None
     if quant != (k_zero is not None) or quant != (v_scale is not None):
@@ -163,29 +174,32 @@ def paged_extend_attention(q: jax.Array, k_pool: jax.Array,
     npb = NP // ppb
 
     def page_map(i, trailing):
-        def index_map(b, jb, pt, pos):
+        def index_map(b, jb, pt, pos, lyr):
             first, last = _needed_pages(pos[b], sx=Sx, ps=ps, np_=NP,
                                         window=window)
             j = jnp.clip(jb * ppb + i, first, last)
             # unmapped pages DMA physical page 0; the body skips them
-            return (jnp.maximum(pt[b * NP + j], 0),) + (0,) * trailing
+            return (lyr[0], jnp.maximum(pt[b * NP + j], 0)) + (0,) * trailing
         return index_map
 
+    # the layer dim is squeezed: the body sees (1, K, ps[, hd]) blocks
     page_in, page_specs = [], []
     for i in range(ppb):
         page_in += [k_pool, v_pool]
-        page_specs += [pl.BlockSpec((1, K, ps, hd), page_map(i, 3))] * 2
+        page_specs += [pl.BlockSpec((None, 1, K, ps, hd),
+                                    page_map(i, 3))] * 2
         if quant:
             page_in += [k_scale, k_zero, v_scale]
-            page_specs += [pl.BlockSpec((1, K, ps), page_map(i, 2))] * 3
+            page_specs += [pl.BlockSpec((None, 1, K, ps),
+                                        page_map(i, 2))] * 3
 
     kernel = functools.partial(_kernel, ps=ps, np_=NP, ppb=ppb, npb=npb,
                                sx=Sx, g=G, heads=K, bq=bq,
                                scale=hd ** -0.5, window=window, quant=quant)
-    row_spec = pl.BlockSpec((1, K, rows, hd), lambda b, jb, pt, pos:
+    row_spec = pl.BlockSpec((1, K, rows, hd), lambda b, jb, pt, pos, lyr:
                             (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, npb),
         in_specs=[row_spec, *page_specs],
         out_specs=row_spec,
@@ -205,6 +219,6 @@ def paged_extend_attention(q: jax.Array, k_pool: jax.Array,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(page_table.astype(jnp.int32).reshape(-1), pos0.astype(jnp.int32), qh,
-      *page_in)
+    )(page_table.astype(jnp.int32).reshape(-1), pos0.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qh, *page_in)
     return jnp.transpose(out.reshape(B, K, Sx, G, hd), (0, 2, 1, 3, 4))

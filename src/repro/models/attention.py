@@ -493,6 +493,12 @@ def _attn_out(p: Dict, out: jax.Array, dt) -> jax.Array:
 # Pages are HEAD-MAJOR so the Pallas kernel's page block ``(1, K, ps, hd)``
 # spans its last dims whole, which the TPU's (8, 128) tiling requires of
 # a block (kernels/paged_extend.py).
+# The functions below take the pool STACKED, ``[L, P, K, ps, hd]``, and a
+# ``layer`` index: the layer scan carries every scanned layer's pool
+# whole and each layer writes and reads its own in place, so no step
+# slices out, relayouts or writes back a layer's pool
+# (docs/SERVING.md#the-pool-through-the-layer-scan).  An unscanned
+# layer's pool is the stack ``pool[None]`` at layer 0.
 # With ``kv_dtype="int8"`` the pools are int8 and carry float32 scale
 # sidecar pools ``ksp``/``kzp``/``vsp`` ([P, K, ps]; asymmetric K,
 # symmetric V — kernels/kv_quant.py): quantized at write time in
@@ -504,7 +510,7 @@ def _attn_out(p: Dict, out: jax.Array, dt) -> jax.Array:
 # a page table [NP] mapping logical page (position // ps) to a physical
 # page (-1 = unmapped), so a token's absolute position is explicit from its
 # (logical page, offset) coordinates and masking is pure position
-# arithmetic.  Writes are scatters into uniquely-owned pages (the serving
+# arithmetic.  Writes go in place into uniquely-owned pages (the serving
 # engine's copy-on-write invariant); reads gather the request's pages into
 # a dense logical view (XLA path) or walk the table page-by-page
 # (kernels/paged_extend.py).
@@ -545,14 +551,16 @@ def paged_kv_cache_def(cfg: ModelConfig, num_pages: int, page_size: int,
     return d
 
 
-def _gather_pages(pool_leaf: jax.Array, page_table: jax.Array) -> jax.Array:
-    """Head-major [P, K, ps, ...] pool + [B, NP] table -> dense logical
-    [B, NP*ps, K, ...].
+def _gather_pages(pool_leaf: jax.Array, layer, page_table: jax.Array
+                  ) -> jax.Array:
+    """Stacked head-major [L, P, K, ps, ...] pool + layer + [B, NP] table
+    -> that layer's dense logical [B, NP*ps, K, ...], gathered straight
+    from the stack (no layer slice first).
 
     Unmapped entries (-1) gather page 0; callers mask them by position.
     """
     idx = jnp.maximum(page_table, 0)
-    g = jnp.moveaxis(pool_leaf[idx], 3, 2)              # [B, NP, ps, K, ...]
+    g = jnp.moveaxis(pool_leaf[layer, idx], 3, 2)       # [B, NP, ps, K, ...]
     B, NP, ps = g.shape[0], g.shape[1], g.shape[2]
     out = g.reshape(B, NP * ps, *g.shape[3:])
     # Mesh serving: the pool is sharded by physical page along 'model',
@@ -566,13 +574,29 @@ def _gather_pages(pool_leaf: jax.Array, page_table: jax.Array) -> jax.Array:
     return shard_activation(out, axes)
 
 
-def _paged_write(pool: Dict, k: jax.Array, v: jax.Array, phys: jax.Array,
-                 off: jax.Array) -> Dict:
-    """Scatter K/V ([..., K, hd]) into head-major pool pages.  phys/off:
-    [B] or [B,Sx] (phys >= P drops the write — the route for pad lanes
-    and unmapped positions).
-    Quantized pools (``ksp`` present) quantize HERE, at write time: the
-    scales land at the same (page, offset) as their int8 rows.
+def _paged_write(pool: Dict, layer, k: jax.Array, v: jax.Array,
+                 phys: jax.Array, off: jax.Array) -> Dict:
+    """Write K/V lanes ([..., K, hd]) into the head-major pages of the
+    stacked pool's ``layer``, in place.  phys/off: [B] or [B, Sx]; a lane
+    with phys >= P is dropped — the route for pad lanes and unmapped
+    positions.  Quantized pools (``ksp`` present) quantize HERE, at write
+    time: the scales land at the same (page, offset) as their int8 rows.
+
+    A scatter costs about the same per lane whether the lane is dropped
+    or not, and a mixed step's [B, Sx] block is mostly pad lanes (its
+    decode rows hold one valid lane each).  So the kept lanes are moved
+    to the front and scattered ``C = max(B, Sx)`` at a time — a decode
+    batch, or one full row — in a loop over the chunks they fill; a
+    block of at most C lanes (decode) is one chunk.
+
+    For K/V, layer, page, kv head and slot are all index dims, so each
+    update window is one ``hd`` row.  A ``[K, hd]`` window
+    (``.at[layer, phys, :, off]``) computes the same values, but TPU
+    layout assignment then relayouts the carried pool token-major and
+    copies it whole at the layer loop's entry and exit.  The scale
+    sidecars take the ``[K]`` window: with scalar windows the compiler
+    flattens them to 1-D, a relayout of the whole sidecar.  On a mesh the
+    pool is sharded by page and the scatter partitions by page.
 
     VERIFY-WRITE-THEN-TRUNCATE (speculative decoding): the engine's
     verify step writes drafted tokens here BEFORE knowing whether they
@@ -580,31 +604,67 @@ def _paged_write(pool: Dict, k: jax.Array, v: jax.Array, phys: jax.Array,
     read path masks by absolute position (``t <= pos``), so positions
     past the committed frontier are never attended, and (b) the engine
     always re-writes positions from the committed frontier forward at
-    the start of the next step — the scatter is write-before-read within
+    the start of the next step — the write comes before the read within
     a step — so a rejected position is overwritten before any query
     position could reach it.  Distinct positions map to distinct
     (page, offset) slots (no ring aliasing), which is why paged engines
     can speculate for every attention/MoE arch; the host merely truncates
     page-table tails (serving/page_pool.py::truncate_tail)."""
+    P = pool["kp"].shape[1]
+    C = max(phys.shape)
+    n = phys.size
+    phys, off = phys.reshape(n), off.reshape(n)
+    k, v = k.reshape(n, *k.shape[-2:]), v.reshape(n, *v.shape[-2:])
     if "ksp" in pool:
         kq, ks, kz = Q.quantize_k(k)
         vq, vs = Q.quantize_v(v)
         vals = {"kp": kq, "vp": vq, "ksp": ks, "kzp": kz, "vsp": vs}
     else:
         vals = {"kp": k, "vp": v}
-    # indices around the head slice broadcast to the leading dims of the
-    # [..., K(, hd)] values
-    return {name: pool[name].at[phys, :, off].set(
-                val.astype(pool[name].dtype), mode="drop")
-            for name, val in vals.items()}
+    heads = jnp.arange(k.shape[-2])
+
+    def scatter(pool, vals, phys, off):
+        out = {}
+        for name, val in vals.items():
+            if name in ("kp", "vp"):    # [n, 1] indices, broadcast to K
+                at = (layer, phys[:, None], heads, off[:, None])
+            else:                       # [K] scales at one (page, slot)
+                at = (layer, phys, slice(None), off)
+            out[name] = pool[name].at[at].set(val.astype(pool[name].dtype),
+                                              mode="drop")
+        return out
+
+    if n <= C:
+        return scatter(pool, vals, phys, off)
+    keep = phys < P
+    pad = -n % C                        # the last chunk's tail: dropped
+    order = jnp.argsort(~keep, stable=True)          # kept lanes first
+
+    def front(a, fill):
+        return jnp.pad(a[order], [(0, pad)] + [(0, 0)] * (a.ndim - 1),
+                       constant_values=fill)
+
+    vals = {name: front(val, 0) for name, val in vals.items()}
+    phys, off = front(phys, P), front(off, 0)
+
+    def chunk(i, pool):
+        def rows(a):
+            return jax.lax.dynamic_slice_in_dim(a, i * C, C)
+        return scatter(pool, {name: rows(val) for name, val in vals.items()},
+                       rows(phys), rows(off))
+
+    n_chunks = (jnp.sum(keep, dtype=jnp.int32) + C - 1) // C
+    return jax.lax.fori_loop(0, n_chunks, chunk, pool)
+
 
 
 def attention_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
-                           pool: Dict, pos: jax.Array,
+                           pool: Dict, layer, pos: jax.Array,
                            page_table: jax.Array, window: Optional[int],
                            impl: Optional[str] = None
                            ) -> Tuple[jax.Array, Dict]:
-    """One-token decode over the page pool.  x: [B,1,d]; pos: [B];
+    """One-token decode over the page pool.  x: [B,1,d]; pool: stacked
+    leaves [L, P, K, ps, ...] of which this is ``layer``; pos: [B];
     page_table: [B, NP] int32.  ``impl="pallas"`` reads the pool with the
     page-table-walking kernel (kernels/paged_extend.py) instead of the
     XLA ``_gather_pages`` densify; the write scatter stays XLA either
@@ -612,44 +672,47 @@ def attention_decode_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
     B = x.shape[0]
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // K
-    P, ps = pool["kp"].shape[0], pool["kp"].shape[2]
+    P, ps = pool["kp"].shape[1], pool["kp"].shape[3]
     NP = page_table.shape[1]
     q, k, v = _qkv(cfg, p, x, pos[:, None])
     with jax.named_scope("kv_write"):
         lpage = jnp.clip(pos // ps, 0, NP - 1)
         phys = jnp.take_along_axis(page_table, lpage[:, None], axis=1)[:, 0]
         phys = jnp.where(phys >= 0, phys, P)            # unmapped -> dropped
-        pool = _paged_write(pool, k[:, 0], v[:, 0], phys, pos % ps)
+        pool = _paged_write(pool, layer, k[:, 0], v[:, 0], phys, pos % ps)
 
     q = q.reshape(B, K, G, hd)
     with jax.named_scope("attn_kernel"):
         if impl == "pallas":
             out = ops.paged_decode_attention(
-                q, pool["kp"], pool["vp"], page_table, pos,
+                q, pool["kp"], pool["vp"], page_table, pos, layer,
                 k_scale=pool.get("ksp"), k_zero=pool.get("kzp"),
                 v_scale=pool.get("vsp"), window=window)
             out = out.reshape(B, 1, H, hd).astype(x.dtype)
         else:
-            out = _decode_read_xla(q, pool, page_table, pos, window,
+            out = _decode_read_xla(q, pool, layer, page_table, pos, window,
                                    x.dtype).reshape(B, 1, H, hd)
     return _attn_out(p, out, x.dtype), pool
 
 
-def _decode_read_xla(q, pool: Dict, page_table: jax.Array, pos: jax.Array,
-                     window: Optional[int], dt) -> jax.Array:
-    """The XLA read of one-token decode over the pool: ``_gather_pages``
-    densify, masked softmax, weighted V.  q: [B, K, G, hd]."""
-    hd, ps = q.shape[-1], pool["kp"].shape[2]
+def _decode_read_xla(q, pool: Dict, layer, page_table: jax.Array,
+                     pos: jax.Array, window: Optional[int], dt) -> jax.Array:
+    """The XLA read of one-token decode over the pool's ``layer``:
+    ``_gather_pages`` densify, masked softmax, weighted V.
+    q: [B, K, G, hd]."""
+    hd, ps = q.shape[-1], pool["kp"].shape[3]
     NP = page_table.shape[1]
     scale = hd ** -0.5
     quant = "ksp" in pool
+
+    def gather(name):
+        return _gather_pages(pool[name], layer, page_table)
+
     if quant:
-        kg = _gather_pages(pool["kp"], page_table)              # [B,L,K,hd]
-        scores = _quant_scores(q, kg,
-                               _gather_pages(pool["ksp"], page_table),
-                               _gather_pages(pool["kzp"], page_table)) * scale
+        kg = gather("kp")                                       # [B,T,K,hd]
+        scores = _quant_scores(q, kg, gather("ksp"), gather("kzp")) * scale
     else:
-        kg = _gather_pages(pool["kp"], page_table).astype(dt)
+        kg = gather("kp").astype(dt)
         scores = jnp.einsum("bkgd,btkd->bkgt", q, kg) * scale
         scores = scores.astype(jnp.float32)
     t = jnp.arange(NP * ps)[None, :]
@@ -659,25 +722,25 @@ def _decode_read_xla(q, pool: Dict, page_table: jax.Array, pos: jax.Array,
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     prob = jax.nn.softmax(scores, axis=-1).astype(dt)
     if quant:
-        return _quant_pv(prob, _gather_pages(pool["vp"], page_table),
-                         _gather_pages(pool["vsp"], page_table))
-    vg = _gather_pages(pool["vp"], page_table).astype(dt)
+        return _quant_pv(prob, gather("vp"), gather("vsp"))
+    vg = gather("vp").astype(dt)
     return jnp.einsum("bkgt,btkd->bkgd", prob, vg)
 
 
 def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
-                           pool: Dict, pos0: jax.Array, window: Optional[int],
-                           page_table: jax.Array,
+                           pool: Dict, layer, pos0: jax.Array,
+                           window: Optional[int], page_table: jax.Array,
                            valid: Optional[jax.Array] = None,
                            impl: Optional[str] = None
                            ) -> Tuple[jax.Array, Dict]:
-    """Multi-token extension over the page pool: x: [B, Sx, d] continues at
-    position pos0 [B]; the engine has already mapped (and COW-resolved)
-    every logical page the valid lanes touch.  Lane l writes page
-    table[(pos0+l)//ps] offset (pos0+l)%ps; invalid lanes never reach the
-    pool.  There is no ring aliasing: distinct positions always land in
-    distinct (page, offset) slots, so — unlike the dense ring path — no
-    lane-deduplication or capacity clamp is needed.
+    """Multi-token extension over the page pool (stacked leaves
+    [L, P, K, ps, ...], of which this is ``layer``): x: [B, Sx, d]
+    continues at position pos0 [B]; the engine has already mapped (and
+    COW-resolved) every logical page the valid lanes touch.  Lane l
+    writes page table[(pos0+l)//ps] offset (pos0+l)%ps; invalid lanes
+    never reach the pool.  There is no ring aliasing: distinct positions
+    always land in distinct (page, offset) slots, so — unlike the dense
+    ring path — no lane-deduplication or capacity clamp is needed.
 
     ``impl="pallas"`` reads the post-write pool with the fused paged
     attention kernel (kernels/paged_extend.py): each mapped page is
@@ -687,7 +750,7 @@ def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
     B, Sx, _ = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // K
-    P, ps = pool["kp"].shape[0], pool["kp"].shape[2]
+    P, ps = pool["kp"].shape[1], pool["kp"].shape[3]
     NP = page_table.shape[1]
     positions = pos0[:, None] + jnp.arange(Sx)[None, :]          # [B,Sx]
     q, k, v = _qkv(cfg, p, x, positions)
@@ -698,39 +761,42 @@ def attention_extend_paged(cfg: ModelConfig, p: Dict, x: jax.Array,
         if valid is not None:
             keep = keep & valid
         phys = jnp.where(keep, phys, P)                          # drop pads
-        pool = _paged_write(pool, k, v, phys, positions % ps)
+        pool = _paged_write(pool, layer, k, v, phys, positions % ps)
 
     q = q.reshape(B, Sx, K, G, hd)
     with jax.named_scope("attn_kernel"):
         if impl == "pallas":
             out = ops.paged_extend_attention(
-                q, pool["kp"], pool["vp"], page_table, pos0,
+                q, pool["kp"], pool["vp"], page_table, pos0, layer,
                 k_scale=pool.get("ksp"), k_zero=pool.get("kzp"),
                 v_scale=pool.get("vsp"), window=window)
             out = out.reshape(B, Sx, H, hd).astype(x.dtype)
         else:
-            out = _extend_read_xla(q, pool, page_table, positions, window,
-                                   x.dtype).reshape(B, Sx, H, hd)
+            out = _extend_read_xla(q, pool, layer, page_table, positions,
+                                   window, x.dtype).reshape(B, Sx, H, hd)
     return _attn_out(p, out, x.dtype), pool
 
 
-def _extend_read_xla(q, pool: Dict, page_table: jax.Array,
+def _extend_read_xla(q, pool: Dict, layer, page_table: jax.Array,
                      positions: jax.Array, window: Optional[int],
                      dt) -> jax.Array:
-    """The XLA read of a multi-token extension over the pool:
+    """The XLA read of a multi-token extension over the pool's ``layer``:
     ``_gather_pages`` densify, masked softmax, weighted V.
     q: [B, Sx, K, G, hd]; positions: [B, Sx]."""
-    hd, ps = q.shape[-1], pool["kp"].shape[2]
+    hd, ps = q.shape[-1], pool["kp"].shape[3]
     NP = page_table.shape[1]
     scale = hd ** -0.5
     quant = "ksp" in pool
+
+    def gather(name):
+        return _gather_pages(pool[name], layer, page_table)
+
     if quant:
-        kg = _gather_pages(pool["kp"], page_table)               # [B,L,K,hd]
-        scores = _quant_scores_ext(
-            q, kg, _gather_pages(pool["ksp"], page_table),
-            _gather_pages(pool["kzp"], page_table)) * scale
+        kg = gather("kp")                                        # [B,T,K,hd]
+        scores = _quant_scores_ext(q, kg, gather("ksp"),
+                                   gather("kzp")) * scale
     else:
-        kg = _gather_pages(pool["kp"], page_table).astype(dt)
+        kg = gather("kp").astype(dt)
         scores = jnp.einsum("bskgd,btkd->bkgst", q, kg) * scale
         scores = scores.astype(jnp.float32)
     t = jnp.arange(NP * ps)[None, None, :]
@@ -741,9 +807,8 @@ def _extend_read_xla(q, pool: Dict, page_table: jax.Array,
     scores = jnp.where(attendable[:, None, None, :, :], scores, NEG_INF)
     prob = jax.nn.softmax(scores, axis=-1).astype(dt)
     if quant:
-        return _quant_pv_ext(prob, _gather_pages(pool["vp"], page_table),
-                             _gather_pages(pool["vsp"], page_table))
-    vg = _gather_pages(pool["vp"], page_table).astype(dt)
+        return _quant_pv_ext(prob, gather("vp"), gather("vsp"))
+    vg = gather("vp").astype(dt)
     return jnp.einsum("bkgst,btkd->bskgd", prob, vg)
 
 
@@ -787,13 +852,15 @@ def attn_block_prefill(cfg: ModelConfig, p: Dict, x: jax.Array,
 def attn_block_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                       pos: jax.Array, kind: str = "attn",
                       page_table: Optional[jax.Array] = None,
-                      impl: Optional[str] = None
+                      impl: Optional[str] = None, layer=None
                       ) -> Tuple[jax.Array, Dict]:
+    """``cache``: a ring cache, or a stacked page pool read and written at
+    ``layer`` (a paged pool layer: it has ``kp``)."""
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if "kp" in cache:                                   # paged pool layer
-        y, cache = attention_decode_paged(cfg, p["attn"], h, cache, pos,
-                                          page_table, block_window(cfg, kind),
-                                          impl=impl)
+        y, cache = attention_decode_paged(cfg, p["attn"], h, cache, layer,
+                                          pos, page_table,
+                                          block_window(cfg, kind), impl=impl)
     else:
         y, cache = attention_decode(cfg, p["attn"], h, cache, pos,
                                     block_window(cfg, kind))
@@ -949,12 +1016,13 @@ def attn_block_extend(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                       pos0: jax.Array, kind: str = "attn",
                       valid: Optional[jax.Array] = None,
                       page_table: Optional[jax.Array] = None,
-                      impl: Optional[str] = None
+                      impl: Optional[str] = None, layer=None
                       ) -> Tuple[jax.Array, Dict]:
+    """``cache`` and ``layer`` as in :func:`attn_block_decode`."""
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if "kp" in cache:                                   # paged pool layer
-        y, cache = attention_extend_paged(cfg, p["attn"], h, cache, pos0,
-                                          block_window(cfg, kind),
+        y, cache = attention_extend_paged(cfg, p["attn"], h, cache, layer,
+                                          pos0, block_window(cfg, kind),
                                           page_table, valid, impl=impl)
     else:
         y, cache = attention_extend(cfg, p["attn"], h, cache, pos0,

@@ -178,12 +178,13 @@ def moe_block_prefill(cfg: ModelConfig, p: Dict, x: jax.Array,
 
 def moe_block_extend(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                      pos0: jax.Array, valid: Optional[jax.Array] = None,
-                     page_table=None, impl: Optional[str] = None):
+                     page_table=None, impl: Optional[str] = None,
+                     layer=None):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if "kp" in cache:                                   # paged pool layer
-        y, cache = A.attention_extend_paged(cfg, p["attn"], h, cache, pos0,
-                                            cfg.sliding_window, page_table,
-                                            valid, impl=impl)
+        y, cache = A.attention_extend_paged(cfg, p["attn"], h, cache, layer,
+                                            pos0, cfg.sliding_window,
+                                            page_table, valid, impl=impl)
     else:
         y, cache = A.attention_extend(cfg, p["attn"], h, cache, pos0,
                                       cfg.sliding_window, valid)
@@ -196,12 +197,12 @@ def moe_block_extend(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
 
 def moe_block_decode(cfg: ModelConfig, p: Dict, x: jax.Array, cache: Dict,
                      pos: jax.Array, page_table=None,
-                     impl: Optional[str] = None):
+                     impl: Optional[str] = None, layer=None):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if "kp" in cache:                                   # paged pool layer
-        y, cache = A.attention_decode_paged(cfg, p["attn"], h, cache, pos,
-                                            page_table, cfg.sliding_window,
-                                            impl=impl)
+        y, cache = A.attention_decode_paged(cfg, p["attn"], h, cache, layer,
+                                            pos, page_table,
+                                            cfg.sliding_window, impl=impl)
     else:
         y, cache = A.attention_decode(cfg, p["attn"], h, cache, pos,
                                       cfg.sliding_window)

@@ -241,14 +241,15 @@ class TransformerLM:
         raise ValueError(kind)
 
     def _apply_block_decode(self, kind: str, p, x, cache, pos,
-                            page_table=None, attn_impl=None):
+                            page_table=None, attn_impl=None, layer=None):
         cfg = self.cfg
         if kind in ("attn", "rg_attn"):
             return A.attn_block_decode(cfg, p, x, cache, pos, kind,
-                                       page_table, impl=attn_impl)
+                                       page_table, impl=attn_impl,
+                                       layer=layer)
         if kind == "moe":
             return MOE.moe_block_decode(cfg, p, x, cache, pos, page_table,
-                                        impl=attn_impl)
+                                        impl=attn_impl, layer=layer)
         if kind == "mamba":
             return M.mamba_block_decode(cfg, p, x, cache)
         if kind == "rglru":
@@ -256,19 +257,79 @@ class TransformerLM:
         raise ValueError(kind)
 
     def _apply_block_extend(self, kind: str, p, x, cache, pos0, valid=None,
-                            page_table=None, attn_impl=None):
+                            page_table=None, attn_impl=None, layer=None):
         cfg = self.cfg
         if kind in ("attn", "rg_attn"):
             return A.attn_block_extend(cfg, p, x, cache, pos0, kind, valid,
-                                       page_table, impl=attn_impl)
+                                       page_table, impl=attn_impl,
+                                       layer=layer)
         if kind == "moe":
             return MOE.moe_block_extend(cfg, p, x, cache, pos0, valid,
-                                        page_table, impl=attn_impl)
+                                        page_table, impl=attn_impl,
+                                        layer=layer)
         if kind == "mamba":
             return M.mamba_block_extend(cfg, p, x, cache, valid)
         if kind == "rglru":
             return RG.rglru_block_extend(cfg, p, x, cache, valid)
         raise ValueError(kind)
+
+    def _step_layers(self, params: PyTree, cache: PyTree, x: jax.Array,
+                     apply) -> Tuple[jax.Array, PyTree]:
+        """Run every layer of a decode or extend step over ``cache``;
+        ``apply(kind, p, x, c, layer)`` runs one block and returns
+        ``(x, c)``.
+
+        A paged attention layer's cache (it has ``kp``) is all pool, and
+        its pool leaves ride the layer scan's CARRY whole, stacked
+        [L, P, K, ps, ...]: each layer writes its new rows and reads its
+        pages in place at the scan's counter.  Scanned as ``xs``/``ys``,
+        the pool would be sliced, relayouted and written back whole at
+        every layer, and copied once more before the loop
+        (docs/SERVING.md#the-pool-through-the-layer-scan).  Every other
+        cache — recurrent state, ring caches — rides ``xs``/``ys``.
+        Unscanned layers hold one layer's pool, passed as ``pool[None]``
+        at layer 0.
+        """
+        def unrolled(x, kinds, params, caches):
+            out = []
+            for kind, p, c in zip(kinds, params, caches):
+                if "kp" not in c:
+                    x, c = apply(kind, p, x, c, None)
+                else:
+                    x, c = apply(kind, p, x,
+                                 {n: a[None] for n, a in c.items()}, 0)
+                    c = {n: a[0] for n, a in c.items()}
+                out.append(c)
+            return x, tuple(out)
+
+        if self.repeats > 1:
+            pools = tuple(c if "kp" in c else None for c in cache["scan"])
+            rest = tuple(None if "kp" in c else c for c in cache["scan"])
+
+            def unit_body(carry, payload):
+                x, pools = carry
+                unit_params, rest, layer = payload
+                new_pools, new_rest = [], []
+                for kind, p, pool, c in zip(self.unit, unit_params, pools,
+                                            rest):
+                    if pool is None:
+                        x, c = apply(kind, p, x, c, None)
+                    else:
+                        x, pool = apply(kind, p, x, pool, layer)
+                    new_pools.append(pool)
+                    new_rest.append(c)
+                return (x, tuple(new_pools)), tuple(new_rest)
+
+            (x, pools), rest = jax.lax.scan(
+                unit_body, (x, pools),
+                (params["scan"], rest, jnp.arange(self.repeats)))
+            scan_caches = tuple(r if p is None else p
+                                for p, r in zip(pools, rest))
+        else:
+            x, scan_caches = unrolled(x, self.unit, params["scan"],
+                                      cache["scan"])
+        x, tail_caches = unrolled(x, self.tail, params["tail"], cache["tail"])
+        return x, {"scan": scan_caches, "tail": tail_caches}
 
     # ---------------- forward (teacher forcing) ----------------------------
 
@@ -405,25 +466,11 @@ class TransformerLM:
         if n_valid is not None:
             valid = jnp.arange(tokens.shape[1])[None, :] < n_valid[:, None]
 
-        def unit_body(x, payload):
-            unit_params, unit_caches = payload
-            new_caches = []
-            for kind, p, c in zip(self.unit, unit_params, unit_caches):
-                x, c = self._apply_block_extend(kind, p, x, c, pos0, valid,
-                                                page_table, attn_impl)
-                new_caches.append(c)
-            return x, tuple(new_caches)
+        def block(kind, p, x, c, layer):
+            return self._apply_block_extend(kind, p, x, c, pos0, valid,
+                                            page_table, attn_impl, layer)
 
-        if self.repeats > 1:
-            x, scan_caches = jax.lax.scan(
-                unit_body, x, (params["scan"], cache["scan"]))
-        else:
-            x, scan_caches = unit_body(x, (params["scan"], cache["scan"]))
-        tail_caches = []
-        for kind, p, c in zip(self.tail, params["tail"], cache["tail"]):
-            x, c = self._apply_block_extend(kind, p, x, c, pos0, valid,
-                                            page_table, attn_impl)
-            tail_caches.append(c)
+        x, cache = self._step_layers(params, cache, x, block)
         x = L.rmsnorm(params["ln_f"], x, self.cfg.norm_eps)
         if all_logits:
             logits = self.unembed(params, x)                    # [B, Sx, V]
@@ -433,7 +480,7 @@ class TransformerLM:
             last = jnp.take_along_axis(
                 x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)[:, 0]
             logits = self.unembed(params, last)
-        return logits, {"scan": scan_caches, "tail": tuple(tail_caches)}
+        return logits, cache
 
     # ---------------- decode -----------------------------------------------
 
@@ -448,25 +495,11 @@ class TransformerLM:
         ``prefill_extend``)."""
         x = self._shard_serve_act(self.embed(params, tokens))
 
-        def unit_body(x, payload):
-            unit_params, unit_caches = payload
-            new_caches = []
-            for kind, p, c in zip(self.unit, unit_params, unit_caches):
-                x, c = self._apply_block_decode(kind, p, x, c, pos,
-                                                page_table, attn_impl)
-                new_caches.append(c)
-            return x, tuple(new_caches)
+        def block(kind, p, x, c, layer):
+            return self._apply_block_decode(kind, p, x, c, pos, page_table,
+                                            attn_impl, layer)
 
-        if self.repeats > 1:
-            x, scan_caches = jax.lax.scan(
-                unit_body, x, (params["scan"], cache["scan"]))
-        else:
-            x, scan_caches = unit_body(x, (params["scan"], cache["scan"]))
-        tail_caches = []
-        for kind, p, c in zip(self.tail, params["tail"], cache["tail"]):
-            x, c = self._apply_block_decode(kind, p, x, c, pos, page_table,
-                                            attn_impl)
-            tail_caches.append(c)
+        x, cache = self._step_layers(params, cache, x, block)
         x = L.rmsnorm(params["ln_f"], x, self.cfg.norm_eps)
         logits = self.unembed(params, x)
-        return logits[:, 0], {"scan": scan_caches, "tail": tuple(tail_caches)}
+        return logits[:, 0], cache

@@ -38,27 +38,42 @@ def _inputs(B=3, Sx=6, K=2, G=4, hd=64, ps=16, NP=8, P=40, seed=0):
     return q, kp, vp, jnp.asarray(pt), pos0
 
 
+def _stack(layer, *pools):
+    """Each pool as layer ``layer`` of a 3-layer stack whose other layers
+    hold its pages in another order (``layer`` None: as they are)."""
+    if layer is None:
+        return pools
+    return tuple(jnp.stack([a if i == layer else jnp.roll(a, i + 1, axis=0)
+                            for i in range(3)]) for a in pools)
+
+
+@pytest.mark.parametrize("layer", [None, 1], ids=["one_layer", "stacked"])
 @pytest.mark.parametrize("window", [None, 48])
 @pytest.mark.parametrize("bq,ppb", [(None, None), (8, 1), (64, 2), (16, 4)])
-def test_extend_kernel_matches_oracle_fp(window, bq, ppb):
+def test_extend_kernel_matches_oracle_fp(window, bq, ppb, layer):
+    """The kernel reads the pool of one layer, alone or in the layer
+    scan's stack (the layer a scalar-prefetch operand)."""
     q, kp, vp, pt, pos0 = _inputs()
-    got = ops.paged_extend_attention(q, kp, vp, pt, pos0, window=window,
-                                     bq=bq, pages_per_block=ppb)
+    got = ops.paged_extend_attention(q, *_stack(layer, kp, vp), pt, pos0,
+                                     layer, window=window, bq=bq,
+                                     pages_per_block=ppb)
     want = ref.paged_extend_attention_ref(q, kp, vp, pt, pos0,
                                           window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("layer", [None, 1], ids=["one_layer", "stacked"])
 @pytest.mark.parametrize("window", [None, 48])
 @pytest.mark.parametrize("ppb", [1, 2])
-def test_extend_kernel_matches_oracle_int8(window, ppb):
+def test_extend_kernel_matches_oracle_int8(window, ppb, layer):
     q, kp, vp, pt, pos0 = _inputs(seed=1)
     kq, ks, kz = Q.quantize_k(kp)
     vq, vs = Q.quantize_v(vp)
-    got = ops.paged_extend_attention(q, kq, vq, pt, pos0, k_scale=ks,
-                                     k_zero=kz, v_scale=vs, window=window,
-                                     pages_per_block=ppb)
+    kq_, vq_, ks_, kz_, vs_ = _stack(layer, kq, vq, ks, kz, vs)
+    got = ops.paged_extend_attention(q, kq_, vq_, pt, pos0, layer,
+                                     k_scale=ks_, k_zero=kz_, v_scale=vs_,
+                                     window=window, pages_per_block=ppb)
     want = ref.paged_extend_attention_ref(q, kq, vq, pt, pos0, k_scale=ks,
                                           k_zero=kz, v_scale=vs,
                                           window=window)

@@ -21,7 +21,9 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ServeConfig
+from repro.kernels import kv_quant as Q
 from repro.kernels import ops, ref
+from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.registry import build_model, get_smoke_config
 from repro.serving.engine import Engine
@@ -121,6 +123,192 @@ def test_engine_paged_matches_ring_tokens(arch):
         if paged:
             eng.pool.check()
     assert outs[True] == outs[False]
+
+
+# ---------------------------------------------------------------------------
+# the pool through the layer scan: carried whole, written in place
+# ---------------------------------------------------------------------------
+
+# scanned attention pools; a MoE scan with an attention tail layer; a
+# hybrid scan of recurrent + windowed layers with a windowed tail
+STEP_MODELS = {
+    "scanned": ("qwen3_0_6b", {}),
+    "tail": ("granite_moe_1b_a400m",
+             dict(num_layers=3, block_pattern=("moe", "moe", "attn"))),
+    "window": ("recurrentgemma_9b",
+               dict(num_layers=5,
+                    block_pattern=("rglru", "rg_attn") * 2 + ("rg_attn",))),
+}
+
+
+def _sliced_layers(m):
+    """The layer loop with every cache, pools included, scanned as the
+    scan's xs/ys: each layer's pool sliced out, written and stacked back
+    — the formulation the carried pool replaced."""
+    def step_layers(params, cache, x, apply):
+        def one(kind, p, x, c):
+            if "kp" not in c:
+                return apply(kind, p, x, c, None)
+            x, c = apply(kind, p, x, {n: a[None] for n, a in c.items()}, 0)
+            return x, {n: a[0] for n, a in c.items()}
+
+        def unit(x, payload):
+            new = []
+            for kind, p, c in zip(m.unit, *payload):
+                x, c = one(kind, p, x, c)
+                new.append(c)
+            return x, tuple(new)
+
+        payload = (params["scan"], cache["scan"])
+        x, scan = (jax.lax.scan(unit, x, payload) if m.repeats > 1
+                   else unit(x, payload))
+        tail = []
+        for kind, p, c in zip(m.tail, params["tail"], cache["tail"]):
+            x, c = one(kind, p, x, c)
+            tail.append(c)
+        return x, {"scan": scan, "tail": tuple(tail)}
+    return step_layers
+
+
+@pytest.mark.parametrize("layers", sorted(STEP_MODELS))
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+@pytest.mark.parametrize("step", ["decode", "mixed", "verify"])
+def test_step_pool_in_place_matches_layer_slices(step, kv_dtype, layers,
+                                                 monkeypatch):
+    """Decode, mixed (``n_valid``) and verify (``all_logits``) steps with
+    the pool carried through the scan give exactly the logits and the
+    cache of the per-layer-slice formulation, and change the pool only
+    at the (layer, page, slot) rows the step writes."""
+    arch, kw = STEP_MODELS[layers]
+    cfg = get_smoke_config(arch).replace(dtype="float32",
+                                         capacity_factor=8.0, **kw)
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    B, Sx, ps, NP = 4, 5, 8, 8
+    P = B * NP + 4
+    rng = np.random.default_rng(1)
+    defs = m.cache_defs_paged(B, P, ps, kv_dtype=kv_dtype)
+    cache = jax.tree_util.tree_map(
+        lambda d: jnp.asarray(
+            rng.integers(-127, 128, d.shape) if d.dtype == jnp.int8
+            else rng.uniform(0.5, 1.5, d.shape), d.dtype),
+        defs, is_leaf=L.is_def)
+    # scattered pages, unmapped tails; rows 0 and 2 cross a page boundary,
+    # row 2 sits past the smoke models' 32-token local window
+    pt = np.full((B, NP), -1, np.int32)
+    pt[:, :7] = rng.permutation(P)[:B * 7].reshape(B, 7)
+    pos0 = np.array([6, 21, 38, 13], np.int32)
+    if step == "decode":
+        toks = rng.integers(3, cfg.vocab_size, (B, 1)).astype(np.int32)
+        n_valid = np.ones(B, np.int32)
+        run = lambda m, c: m.decode_step(                 # noqa: E731
+            params, c, jnp.asarray(toks), jnp.asarray(pos0),
+            page_table=jnp.asarray(pt))
+    else:
+        toks = rng.integers(3, cfg.vocab_size, (B, Sx)).astype(np.int32)
+        # runs of several lanes, a decode row (one lane), a no-op row
+        n_valid = np.array([Sx, 2, 0, 1] if step == "mixed"
+                           else [Sx, Sx, 3, 1], np.int32)
+        run = lambda m, c: m.prefill_extend(              # noqa: E731
+            params, c, jnp.asarray(toks), jnp.asarray(pos0),
+            jnp.asarray(n_valid), page_table=jnp.asarray(pt),
+            all_logits=step == "verify")
+
+    lg, out = jax.jit(lambda c: run(m, c))(cache)
+    monkeypatch.setattr(m, "_step_layers", _sliced_layers(m))
+    lg_ref, out_ref = jax.jit(lambda c: run(m, c))(cache)
+    np.testing.assert_array_equal(np.asarray(lg), np.asarray(lg_ref))
+    jax.tree_util.tree_map(np.testing.assert_array_equal, out, out_ref)
+
+    written = np.zeros((P, ps), bool)
+    for b in range(B):
+        for t in range(pos0[b], pos0[b] + n_valid[b]):
+            written[pt[b, t // ps], t % ps] = True
+    for leaf, new, old in zip(jax.tree_util.tree_leaves(defs),
+                              jax.tree_util.tree_leaves(out),
+                              jax.tree_util.tree_leaves(cache)):
+        if "pages" not in leaf.axes:
+            continue
+        new, old = np.asarray(new), np.asarray(old)
+        ax = leaf.axes.index("pages")                    # 0 tail, 1 scan
+        rows = written.reshape(written.shape[:1] + (1,) + written.shape[1:])
+        rows = rows.reshape((1,) * ax + rows.shape
+                            + (1,) * (new.ndim - ax - 3))
+        rows = np.broadcast_to(rows, new.shape)
+        np.testing.assert_array_equal(new[~rows], old[~rows])
+        # every written slot of every layer took new values
+        changed = np.moveaxis(new != old, (ax, ax + 2), (0, 1))
+        changed = changed.reshape(P, ps, -1).any(-1)
+        assert changed[written].all()
+
+
+# lanes valid in each row of a [4, 5] block: none, one lane a row (a
+# decode batch), runs of several (more than one chunk), the whole block,
+# and a decode step's [4] lanes (one chunk, nothing to gather)
+WRITE_LANES = {"none": [0, 0, 0, 0], "one": [1, 1, 1, 1],
+               "several": [5, 2, 0, 4], "all": [5, 5, 5, 5], "decode": None}
+
+
+@pytest.mark.parametrize("lanes", sorted(WRITE_LANES))
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+def test_paged_write_matches_row_scatter(quant, lanes):
+    """``_paged_write`` leaves the stacked pool exactly as a plain scatter
+    of every lane's K/V row at (layer, page, :, slot) with dropped lanes
+    dropped: the kept lanes gathered to the front and written a chunk at
+    a time lose no lane, write none twice and touch no other slot.  Runs
+    of Sx < page_size lanes start near a page's end and cross into the
+    next page."""
+    B, Sx, K, hd, ps, NL, layer = 4, 5, 2, 16, 8, 3, 1
+    P = 4 * B
+    rng = np.random.default_rng(3)
+    pool = {"kp": rng.uniform(-1, 1, (NL, P, K, ps, hd)),
+            "vp": rng.uniform(-1, 1, (NL, P, K, ps, hd))}
+    if quant:
+        pool = {n: rng.integers(-127, 128, a.shape) for n, a in pool.items()}
+        pool.update({n: rng.uniform(0.5, 1.5, (NL, P, K, ps))
+                     for n in ("ksp", "kzp", "vsp")})
+    pool = {n: jnp.asarray(a, jnp.int8 if a.dtype == np.int64
+                           else jnp.float32) for n, a in pool.items()}
+    pt = rng.permutation(P).reshape(B, 4).astype(np.int32)
+    pt[3, 1:] = -1                          # row 3 maps one page only
+    pos0 = np.array([5, 14, 7, 3], np.int32)
+    if WRITE_LANES[lanes] is None:          # decode: one lane a row
+        shape, positions = (B,), pos0
+        keep = np.ones(B, bool)
+    else:
+        shape = (B, Sx)
+        positions = pos0[:, None] + np.arange(Sx)
+        keep = np.arange(Sx) < np.array(WRITE_LANES[lanes])[:, None]
+    phys = np.take_along_axis(pt.reshape(B, -1), (positions // ps).reshape(
+        B, -1), axis=1).reshape(shape)
+    # dropped lanes, and row 3's lanes past its one mapped page -> P
+    phys = np.where(keep & (phys >= 0), phys, P).astype(np.int32)
+    off = (positions % ps).astype(np.int32)
+    k = jnp.asarray(rng.standard_normal(shape + (K, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape + (K, hd)), jnp.float32)
+
+    out = jax.jit(A._paged_write)(pool, layer, k, v, jnp.asarray(phys),
+                                  jnp.asarray(off))
+
+    @jax.jit
+    def row_scatter(pool, k, v):
+        # the lanes as a flat run of rows, quantized as the writer does
+        k, v = k.reshape(-1, K, hd), v.reshape(-1, K, hd)
+        if quant:
+            kq, ks, kz = Q.quantize_k(k)
+            vq, vs = Q.quantize_v(v)
+            vals = {"kp": kq, "vp": vq, "ksp": ks, "kzp": kz, "vsp": vs}
+        else:
+            vals = {"kp": k, "vp": v}
+        return {name: pool[name].at[layer, phys.reshape(-1), :,
+                                    off.reshape(-1)].set(
+                    val.astype(pool[name].dtype), mode="drop")
+                for name, val in vals.items()}
+
+    want = row_scatter(pool, k, v)
+    for name in pool:
+        np.testing.assert_array_equal(np.asarray(out[name]),
+                                      np.asarray(want[name]), err_msg=name)
 
 
 # ---------------------------------------------------------------------------

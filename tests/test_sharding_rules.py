@@ -166,6 +166,60 @@ def test_sharded_serving_greedy_parity_host_mesh():
     assert "PARITY_OK" in out.stdout
 
 
+_MESH_WRITE_CHILD = textwrap.dedent("""
+    import re, sys
+    import jax
+    from repro.configs.base import ServeConfig
+    from repro.models.registry import build_model, get_smoke_config
+    from repro.serving.engine import Engine
+
+    P = 116                     # pages: 29 a device, a count no other dim has
+    cfg = get_smoke_config("qwen3_0_6b").replace(
+        dtype="float32", num_layers=4, block_pattern=("attn",) * 4)
+    m = build_model(cfg)
+    eng = Engine(m, m.init(jax.random.PRNGKey(0)),
+                 ServeConfig(max_batch=4, max_seq=128, page_size=8,
+                             num_pages=P, mesh="1x4", spec_decode=True,
+                             spec_tokens=4, kv_dtype=sys.argv[1] or None))
+    eng.aot_compile()
+    pool = [a for a in jax.tree_util.tree_leaves(eng.cache) if a.ndim == 5]
+    assert pool and all(a.shape[1] == P for a in pool)
+    assert all(a.sharding.spec[1] == "model" for a in pool)
+    exes = eng.compiled_steps()
+    assert len(exes) >= 3               # decode, mixed, verify
+    moved = []
+    for exe in exes:
+        for res, op in re.findall(
+                r"= (.+?) (all-gather|all-to-all|all-reduce|reduce-scatter|"
+                r"collective-permute)(?:-start)?\\(", exe.as_text()):
+            dims = [d.split(",") for d in re.findall(r"\\[([\\d,]*)\\]", res)]
+            if any(str(P) in d for d in dims):
+                moved.append(op + " " + res)
+    assert not moved, moved
+    print("MESH_WRITE_OK", len(exes))
+""")
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["fp", "int8"])
+def test_mesh_step_programs_gather_no_pool(kv_dtype):
+    """Under a 1x4 host mesh, with the pool's pages sharded on 'model',
+    the compiled decode, mixed and verify programs write the new K/V rows
+    and read the pages with no collective over the pool: a write that
+    reads a window at a traced page (``dynamic_slice``) and writes it
+    back all-gathers the stacked pool instead.  Child process: the
+    host-device-count flag must precede jax init."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(repo, "src")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    out = subprocess.run([sys.executable, "-c", _MESH_WRITE_CHILD, kv_dtype],
+                         capture_output=True, text=True, env=env,
+                         timeout=300, cwd=repo)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert "MESH_WRITE_OK" in out.stdout
+
+
 # ---------------------------------------------------------------------------
 # hlocost
 # ---------------------------------------------------------------------------
